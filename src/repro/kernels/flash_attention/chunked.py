@@ -4,22 +4,24 @@ Same function as ``kernel.flash_attention_tpu`` (causal / sliding-window,
 GQA), different implementation point: the lazy two-pass softmax of Rabe &
 Staats (arXiv:2112.05682) instead of the online single-pass rescale.
 
-  grid = (batch * q_heads, n_q_blocks) — no kv grid axis. Each program
-  holds its q block and streams K/V through an inner ``fori_loop`` in
-  (bk, d) chunks, twice:
+  grid = (batch * q_heads, n_q_blocks, 2 * n_kv_blocks), the last axis
+  sequential. K/V stream through VMEM in (bk, d) tiles, twice: the first
+  n_kv_blocks steps are pass 1, the rest pass 2.
 
-    pass 1:  m  = max over all kv chunks of masked q·kᵀ rows
+    pass 1:  m  = max over all kv tiles of masked q·kᵀ rows
     pass 2:  l += Σ exp(s - m);  acc += exp(s - m) · v
 
   Because ``m`` is final before any accumulation starts, the accumulator
   is never rescaled — the per-chunk ``exp(m_prev - m_new)`` corrections
   of the online algorithm (two extra VPU passes over (bq, d) + (bq, bk)
-  per chunk) disappear, at the price of reading K twice. That trades
+  per chunk) disappear, at the price of reading K twice (V's index map
+  holds tile 0 through pass 1, so V is read once). That trades
   bandwidth for vector work: a second implementation point on the
   energy frontier, cheaper where exp/multiply throughput is the bound
   (little cores) and dearer where HBM bandwidth is. The (bq, skv) score
   matrix is never materialized — the live set is one (bq, bk) tile plus
-  the (bq, d) accumulator, and no VMEM scratch carries across grid steps.
+  the (bq, d) accumulator and the (bq, 128) max/sum rows, whatever the
+  sequence length.
 
 Validated in interpret mode against ref.py on CPU (tests/test_kernels.py);
 TPU is the compile target.
@@ -32,57 +34,56 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+LANES = 128
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
-            bq, bk, nk, seq_kv):
+def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
+            causal, window, bq, bk, nk, seq_kv):
     qi = pl.program_id(1)
-    q_start = qi * bq
+    j = pl.program_id(2)
+    ki = j % nk
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
     q = q_ref[0, 0].astype(jnp.float32)            # (bq, d)
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k = k_ref[0, 0].astype(jnp.float32)            # (bk, d)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale   # (bq, bk)
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kv_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    mask = kv_pos < seq_kv
+    if causal:
+        mask &= kv_pos <= q_pos
+    if window > 0:
+        mask &= kv_pos > q_pos - window
 
-    def masked_scores(ki):
-        k_start = ki * bk
-        k = k_ref[0, 0, pl.ds(k_start, bk), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        kv_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 1)
-        mask = kv_pos < seq_kv
-        if causal:
-            mask &= kv_pos <= q_pos
-        if window > 0:
-            mask &= kv_pos > q_pos - window
-        return s, mask
+    @pl.when(j < nk)
+    def _max_pass():
+        m_cur = jnp.max(jnp.where(mask, s, NEG), axis=1, keepdims=True)
+        m_ref[...] = jnp.maximum(m_ref[...], m_cur)
 
-    def max_body(ki, m):
-        s, mask = masked_scores(ki)
-        s = jnp.where(mask, s, NEG)
-        return jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-
-    m = jax.lax.fori_loop(
-        0, nk, max_body, jnp.full((bq, 1), NEG, jnp.float32))
-
-    def acc_body(ki, carry):
-        l, acc = carry
-        s, mask = masked_scores(ki)
-        k_start = ki * bk
-        v = v_ref[0, 0, pl.ds(k_start, bk), :].astype(jnp.float32)
-        p = jnp.where(mask, jnp.exp(s - m), 0.0)   # (bq, bk)
-        l = l + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc + jax.lax.dot_general(
+    @pl.when(j >= nk)
+    def _acc_pass():
+        v = v_ref[0, 0].astype(jnp.float32)        # (bk, d)
+        p = jnp.where(mask, jnp.exp(s - m_ref[:, :1]), 0.0)   # (bq, bk)
+        l_ref[...] += jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] += jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return l, acc
 
-    l, acc = jax.lax.fori_loop(
-        0, nk, acc_body,
-        (jnp.zeros((bq, 1), jnp.float32),
-         jnp.zeros((bq, q.shape[1]), jnp.float32)))
-    o_ref[0, 0, :, :] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    @pl.when(j == 2 * nk - 1)
+    def _finalize():
+        o_ref[0, 0, :, :] = (acc_ref[...] /
+                             jnp.maximum(l_ref[:, :1], 1e-30)
+                             ).astype(o_ref.dtype)
 
 
 def chunked_attention_tpu(q, k, v, *, causal=True, window=0, bq=128,
@@ -109,20 +110,25 @@ def chunked_attention_tpu(q, k, v, *, causal=True, window=0, bq=128,
         bq=bq, bk=bk, nk=nk, seq_kv=skv)
     out = pl.pallas_call(
         kernel,
-        grid=(b * hq, nq),
+        grid=(b * hq, nq, 2 * nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
-                         lambda bh, qi: (bh // hq, bh % hq, qi, 0)),
-            pl.BlockSpec((1, 1, skv + pad_k, d),
-                         lambda bh, qi: (bh // hq, (bh % hq) // group,
-                                         0, 0)),
-            pl.BlockSpec((1, 1, skv + pad_k, d),
-                         lambda bh, qi: (bh // hq, (bh % hq) // group,
-                                         0, 0)),
+                         lambda bh, qi, j: (bh // hq, bh % hq, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bh, qi, j: (bh // hq, (bh % hq) // group,
+                                            j % nk, 0)),
+            pl.BlockSpec((1, 1, bk, d),
+                         lambda bh, qi, j: (bh // hq, (bh % hq) // group,
+                                            jnp.maximum(j - nk, 0), 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bh, qi: (bh // hq, bh % hq, qi, 0)),
+                               lambda bh, qi, j: (bh // hq, bh % hq, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq + pad_q, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :sq]

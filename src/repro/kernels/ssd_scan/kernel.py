@@ -6,7 +6,7 @@ traffic per chunk is exactly one (Q, P) x-block, one (Q, N) B/C block pair
 and one (Q, P) y-block, the roofline-optimal schedule for SSD.
 
 Per chunk (block decomposition of Dao & Gu 2024):
-  seg   = cumsum(dt * A)                       (Q,)
+  seg   = prefix sum of dt * A                 (Q,)
   y_in  = (C B^T ⊙ decay ⊙ dt) · x   (masked causal, quadratic in Q)
   y_out = C · S_prev^T scaled by e^{seg}
   S     = e^{seg_Q} S_prev + Σ_j e^{seg_Q - seg_j} dt_j x_j ⊗ B_j
@@ -33,7 +33,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
 
     x = x_ref[0, 0].astype(jnp.float32)       # (Q, P)
     dt = dt_ref[0, 0].astype(jnp.float32)     # (Q, 1) broadcast later
-    a = a_ref[0].astype(jnp.float32)          # scalar A_h
+    a = a_ref[pl.program_id(1)]               # scalar A_h from SMEM
     bm = b_ref[0].astype(jnp.float32)         # (Q, N)
     cm = c_ref[0].astype(jnp.float32)         # (Q, N)
     q = x.shape[0]
@@ -42,14 +42,17 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0)
     dt = jnp.where(pos < seq_len, dt, 0.0)
 
+    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     da = dt * a                                # (Q, 1)
-    seg = jnp.cumsum(da, axis=0)               # (Q, 1)
+    # the chip's kernel compiler has no cumsum: prefix sum as a masked
+    # (Q, Q) row reduction
+    seg = jnp.sum(jnp.where(jj <= ii, da.T, 0.0), axis=1,
+                  keepdims=True)               # (Q, 1)
     # intra-chunk quadratic term
     g = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, Q)
     decay = jnp.exp(seg - seg.T)               # (Q, Q) e^{seg_i - seg_j}
-    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     m = jnp.where(ii >= jj, g * decay, 0.0) * dt.T  # (Q, Q) ⊙ dt_j
     y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, P)
@@ -96,7 +99,9 @@ def ssd_tpu(x, dt, a, bmat, cmat, *, chunk=128, interpret=False):
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            # A whole in SMEM: a (1,) VMEM block of an (H,) vector is not
+            # aligned to the 128-lane tiling, and the chip refuses it
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
         ],
@@ -110,6 +115,6 @@ def ssd_tpu(x, dt, a, bmat, cmat, *, chunk=128, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, a, bmat, cmat)
+    )(xt, dtt, a.astype(jnp.float32), bmat, cmat)
     y = y.transpose(0, 2, 1, 3)[:, :l]
     return y, state.transpose(0, 1, 3, 2)  # -> (B, H, P, N)

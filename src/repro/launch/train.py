@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.ckpt import CheckpointManager
 from repro.data import Prefetcher, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import get_config, get_smoke_config
 from repro.models.transformer import Model
 from repro.sharding import use_ctx
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)

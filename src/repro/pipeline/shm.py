@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import multiprocessing
 import pickle
+import sys
 import time
 from multiprocessing import shared_memory
 
@@ -70,14 +71,34 @@ class Full(Exception):
     """put() timed out: no free slot became available."""
 
 
+def _held_accelerator() -> str | None:
+    """Platform of a non-CPU JAX backend this process has initialised,
+    if any. Asks without initialising one."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    return next((name for name in xla_bridge.backends() if name != "cpu"),
+                None)
+
+
 def fork_context():
     """The ``fork`` multiprocessing context the process executor runs
     on (workers inherit stage fns, shm mappings and semaphores — no
-    pickling). Raises on platforms without fork."""
+    pickling). Raises on platforms without fork, and in a process that
+    holds an accelerator: the chip belongs to one process, so a forked
+    child that touches it fails or hangs."""
     if "fork" not in multiprocessing.get_all_start_methods():
         raise RuntimeError(
             "the process executor needs the 'fork' start method "
             "(Linux/macOS); this platform does not provide it")
+    held = _held_accelerator()
+    if held is not None:
+        raise RuntimeError(
+            f"the process executor cannot fork: this process has "
+            f"initialised the JAX {held!r} backend, and its children "
+            f"cannot share the device; use executor='thread'")
     return multiprocessing.get_context("fork")
 
 

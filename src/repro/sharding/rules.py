@@ -17,13 +17,8 @@ import threading
 from typing import Any, Sequence
 
 import jax
+from jax import shard_map  # noqa: F401  (re-exported for model code)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# jax moved shard_map from jax.experimental to the top level; resolve once
-# here so model code runs on either side of the move.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 # Default logical-axis -> mesh-axis rules for the production meshes
 # (data, model) and (pod, data, model).

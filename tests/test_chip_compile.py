@@ -1,0 +1,104 @@
+"""Compile the served path's kernels and step for a described TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler builds each program for a
+``v5e:2x2`` topology that is described, not attached, and refuses what
+the chip would refuse (misaligned blocks, VMEM overflow, a step that does
+not fit in HBM). Interpret-mode tests cannot see those faults.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every test worker
+imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention.chunked import chunked_attention_tpu
+from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.ssd_scan.kernel import ssd_tpu
+from repro.models.config import get_config
+from repro.models.transformer import Model
+
+# one v5e chip has 16 GiB of HBM; leave room for what the process holds
+# besides the step program
+DECODE_STEP_BUDGET_BYTES = 14e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile_kernel(fn, *args):
+    """Compile ``fn`` for the described chip; the Pallas kernel must be in
+    the program as a TPU custom call, not lowered to something else."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_stablelm_widths(one_chip):
+    # stablelm-3b: 32 q heads = 32 kv heads, head_dim 80, 2k prefill
+    q = _spec((1, 32, 2048, 80), jnp.bfloat16, one_chip)
+    _compile_kernel(
+        lambda q, k, v: flash_attention_tpu(q, k, v, causal=True), q, q, q)
+
+
+def test_ssd_mamba2_widths(one_chip):
+    # mamba2-1.3b: d_inner 4096 / head_dim 64 = 64 heads, d_state 128
+    b, l, h, p, n = 1, 2048, 64, 64, 128
+    x = _spec((b, l, h, p), jnp.bfloat16, one_chip)
+    dt = _spec((b, l, h), jnp.float32, one_chip)
+    a = _spec((h,), jnp.float32, one_chip)
+    bc = _spec((b, l, n), jnp.bfloat16, one_chip)
+    _compile_kernel(
+        lambda x, dt, a, bm, cm: ssd_tpu(x, dt, a, bm, cm, chunk=256),
+        x, dt, a, bc, bc)
+
+
+def test_chunked_attention_long_context(one_chip):
+    # K/V stream through VMEM in tiles, so a 32k context fits; the kernel
+    # used to hold the whole K/V sequence per grid step and ran out of VMEM
+    q = _spec((1, 32, 32768, 80), jnp.bfloat16, one_chip)
+    _compile_kernel(
+        lambda q, k, v: chunked_attention_tpu(q, k, v, causal=True), q, q, q)
+
+
+def test_stablelm_decode_step_fits_one_chip(one_chip):
+    """The served step at full width, 8 slots x 1024 positions, fits in
+    one chip's HBM with room to spare."""
+    model = Model(get_config("stablelm-3b"))
+    place = lambda s: _spec(s.shape, s.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(place, model.abstract_params())
+    cache = jax.tree.map(place, model.init_cache(8, 1024, abstract=True))
+    tokens = _spec((8,), jnp.int32, one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < DECODE_STEP_BUDGET_BYTES, (
+        f"decode step needs {used / 1e9:.2f} GB "
+        f"(args {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+        f"temps {mem.temp_size_in_bytes / 1e9:.2f} GB)")

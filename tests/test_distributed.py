@@ -20,7 +20,7 @@ _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.sharding import use_ctx
 from repro.models.attention import (context_attention, decode_attention,
                                     naive_attention, decode_attention_local)
@@ -29,7 +29,8 @@ from repro.models.moe import moe_apply, moe_dense_oracle
 from repro.models.config import MoEConfig, get_smoke_config
 from repro.models.transformer import Model
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 ok = []
 
@@ -132,7 +133,7 @@ print("PASS", len(ok), "checks:", ",".join(ok))
 def test_distributed_parity_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                          capture_output=True, text=True, timeout=880)
     assert res.returncode == 0, f"STDOUT:\n{res.stdout}\nSTDERR:\n{res.stderr[-4000:]}"

@@ -114,3 +114,15 @@ def test_cross_process_transfer():
                 payload, np.full(5, seq, dtype=np.float64))
     finally:
         q.destroy()
+
+
+def test_fork_refused_when_accelerator_held(monkeypatch):
+    """A parent holding a non-CPU JAX backend must not fork workers: the
+    child cannot reach the chip and would fail or hang."""
+    from jax._src import xla_bridge
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends",
+                        lambda: {"cpu": object(), "tpu": object()})
+    with pytest.raises(RuntimeError, match="'tpu' backend"):
+        fork_context()
