@@ -615,48 +615,62 @@ class Model:
         c = self.cfg
         b = x.shape[0]
         k_cache, v_cache = cache_kv
-        h = rms_norm(x, p[prefix + "ln_attn"], c.norm_eps)
-        q = (h @ p[prefix + "wq"]).reshape(b, 1, c.n_heads, c.hd)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, p[prefix + "ln_attn"], c.norm_eps)
+            q = (h @ p[prefix + "wq"]).reshape(b, 1, c.n_heads, c.hd)
+            if not cross:
+                k = (h @ p[prefix + "wk"]).reshape(b, 1, c.n_kv_heads, c.hd)
+                v = (h @ p[prefix + "wv"]).reshape(b, 1, c.n_kv_heads, c.hd)
+                # pos is per-slot (B,): each lane rotates and writes at its
+                # own position, so mid-run admissions decode exactly as if
+                # solo
+                pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
+                sin, cos = rope_table(pos_b[:, None], c.hd, c.rope_theta)
+                q = apply_rope(q, sin, cos)
+                k = apply_rope(k, sin, cos)
         if not cross:
-            k = (h @ p[prefix + "wk"]).reshape(b, 1, c.n_kv_heads, c.hd)
-            v = (h @ p[prefix + "wv"]).reshape(b, 1, c.n_kv_heads, c.hd)
-            # pos is per-slot (B,): each lane rotates and writes at its own
-            # position, so mid-run admissions decode exactly as if solo
-            pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
-            sin, cos = rope_table(pos_b[:, None], c.hd, c.rope_theta)
-            q = apply_rope(q, sin, cos)
-            k = apply_rope(k, sin, cos)
-            if rolling:
-                slot = pos_b % k_cache.shape[1]
-            else:
-                slot = jnp.minimum(pos_b, k_cache.shape[1] - 1)
-            k_cache = k_cache.at[jnp.arange(b), slot].set(
-                k[:, 0].astype(k_cache.dtype))
-            v_cache = v_cache.at[jnp.arange(b), slot].set(
-                v[:, 0].astype(v_cache.dtype))
+            with jax.named_scope("kv_write"):
+                if rolling:
+                    slot = pos_b % k_cache.shape[1]
+                else:
+                    slot = jnp.minimum(pos_b, k_cache.shape[1] - 1)
+                k_cache = k_cache.at[jnp.arange(b), slot].set(
+                    k[:, 0].astype(k_cache.dtype))
+                v_cache = v_cache.at[jnp.arange(b), slot].set(
+                    v[:, 0].astype(v_cache.dtype))
             att_pos = pos_b
         else:
             att_pos = jnp.int32(k_cache.shape[1] - 1)  # attend to all enc kv
-        o = decode_attention(q[:, 0], k_cache, v_cache, pos=att_pos,
-                             window=0 if rolling or cross else window)
-        o = o.reshape(b, 1, -1) @ p[prefix + "wo"]
-        return x + o, (k_cache, v_cache)
+        with jax.named_scope("attn"):
+            o = decode_attention(q[:, 0], k_cache, v_cache, pos=att_pos,
+                                 window=0 if rolling or cross else window)
+            o = o.reshape(b, 1, -1) @ p[prefix + "wo"]
+            return x + o, (k_cache, v_cache)
 
     def decode_step(self, params: Params, cache, tokens: jax.Array):
-        """tokens (B,) int32 -> (next_tokens (B,), cache')."""
+        """tokens (B,) int32 -> (next_tokens (B,), cache').
+
+        Named scopes mark the planner's tasks in the HLO metadata, where a
+        profiler shows each op's scope path; they leave the compiled code
+        as it is: ``embed``; ``layer`` (a scan body) holding ``attn``,
+        ``kv_write`` (the cache writes) and ``ffn``, or ``ssm``; ``head``
+        (final norm and greedy pick)."""
         c = self.cfg
         cdt = _dt(c.compute_dtype)
         b = tokens.shape[0]
         pos = cache["pos"]
-        x = embedloss.embed_in(params["embed"], tokens[:, None], cdt)
-        x = shard(x, "batch", None, None)
+        with jax.named_scope("embed"):
+            x = embedloss.embed_in(params["embed"], tokens[:, None], cdt)
+            x = shard(x, "batch", None, None)
         newc = dict(cache)
 
         if c.kind in ("dense", "moe", "vlm") and c.window <= 0:
             def body(xx, xs):
                 p, kc, vc = xs
-                xx, (kc, vc) = self._attn_decode(p, xx, (kc, vc), pos)
-                xx = self._ffn(p, xx)
+                with jax.named_scope("layer"):
+                    xx, (kc, vc) = self._attn_decode(p, xx, (kc, vc), pos)
+                    with jax.named_scope("ffn"):
+                        xx = self._ffn(p, xx)
                 return xx, (kc, vc)
             x, (newc["k"], newc["v"]) = _scan(
                 body, x, (params["layers"], cache["k"], cache["v"]))
@@ -665,10 +679,12 @@ class Model:
         elif c.kind == "ssm":
             def body(xx, xs):
                 p, conv, st = xs
-                h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
-                y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
-                                            ssd_state=st)
-                return xx + y, (conv, st)
+                with jax.named_scope("layer"), jax.named_scope("ssm"):
+                    h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
+                    y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
+                                                ssd_state=st)
+                    xx = xx + y
+                return xx, (conv, st)
             x, (newc["conv"], newc["state"]) = _scan(
                 body, x, (params["layers"], cache["conv"], cache["state"]))
         elif c.kind == "hybrid":
@@ -676,17 +692,20 @@ class Model:
         elif c.kind in ("encdec", "audio"):
             def body(xx, xs):
                 p, ks, vs, kc, vc = xs
-                xx, (ks, vs) = self._attn_decode(p, xx, (ks, vs), pos)
-                xx, _ = self._attn_decode(p, xx, (kc, vc), pos, prefix="c",
-                                          cross=True)
-                xx = self._ffn(p, xx)
+                with jax.named_scope("layer"):
+                    xx, (ks, vs) = self._attn_decode(p, xx, (ks, vs), pos)
+                    xx, _ = self._attn_decode(p, xx, (kc, vc), pos,
+                                              prefix="c", cross=True)
+                    with jax.named_scope("ffn"):
+                        xx = self._ffn(p, xx)
                 return xx, (ks, vs)
             x, (newc["k_self"], newc["v_self"]) = _scan(
                 body, x, (params["dec"], cache["k_self"], cache["v_self"],
                           cache["k_cross"], cache["v_cross"]))
-        x = rms_norm(x, params["ln_final"], c.norm_eps)
-        nxt = embedloss.greedy(x[:, 0], params["embed"],
-                                valid_vocab=self.cfg.vocab)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["ln_final"], c.norm_eps)
+            nxt = embedloss.greedy(x[:, 0], params["embed"],
+                                   valid_vocab=self.cfg.vocab)
         newc["pos"] = pos + 1
         return nxt, newc
 
@@ -695,16 +714,21 @@ class Model:
 
         def local_body(xx, xs):
             p, kc, vc = xs
-            xx, (kc, vc) = self._attn_decode(p, xx, (kc, vc), pos,
-                                             rolling=True)
-            xx = self._ffn(p, xx)
+            with jax.named_scope("layer"):
+                xx, (kc, vc) = self._attn_decode(p, xx, (kc, vc), pos,
+                                                 rolling=True)
+                with jax.named_scope("ffn"):
+                    xx = self._ffn(p, xx)
             return xx, (kc, vc)
 
         def super_body(xx, xs):
             p, kl, vl, kg, vg = xs
             xx, (kl, vl) = _scan(local_body, xx, (p["local"], kl, vl))
-            xx, (kg, vg) = self._attn_decode(p["global"], xx, (kg, vg), pos)
-            xx = self._ffn(p["global"], xx)
+            with jax.named_scope("layer"):
+                xx, (kg, vg) = self._attn_decode(p["global"], xx, (kg, vg),
+                                                 pos)
+                with jax.named_scope("ffn"):
+                    xx = self._ffn(p["global"], xx)
             return xx, (kl, vl, kg, vg)
 
         stacked = {"local": params["local"], "global": params["global"]}
@@ -724,17 +748,20 @@ class Model:
 
         def mamba_body(xx, xs):
             p, conv, st = xs
-            h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
-            y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
-                                        ssd_state=st)
-            xx = xx + y
+            with jax.named_scope("layer"), jax.named_scope("ssm"):
+                h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
+                y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
+                                            ssd_state=st)
+                xx = xx + y
             return xx, (conv, st)
 
         def super_body(xx, xs):
             p, conv, st, ks, vs = xs
             xx, (conv, st) = _scan(mamba_body, xx, (p, conv, st))
-            xx, (ks, vs) = self._attn_decode(shared, xx, (ks, vs), pos)
-            xx = self._ffn(shared, xx)
+            with jax.named_scope("layer"):
+                xx, (ks, vs) = self._attn_decode(shared, xx, (ks, vs), pos)
+                with jax.named_scope("ffn"):
+                    xx = self._ffn(shared, xx)
             return xx, (conv, st, ks, vs)
 
         x, (newc["conv"], newc["state"], newc["k_shared"],

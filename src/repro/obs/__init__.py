@@ -10,7 +10,9 @@ argument (duck-typed, optional, default off), so the layering in
 
   - :mod:`repro.obs.trace`   — :class:`Tracer`: monotonic-clock spans,
     instants and counter samples recorded into per-thread ring buffers
-    (no locks on the hot path, bounded memory, explicit :meth:`drain`);
+    (no locks on the hot path, bounded memory, explicit :meth:`drain`),
+    and :class:`Span`, the one span helper: a block timed into the
+    ``jax.profiler`` trace and, given an enabled tracer, into its ring;
   - :mod:`repro.obs.metrics` — :class:`MetricsRegistry`: plain-dict
     counters, gauges and windowed histograms (p50/p95/p99);
   - :mod:`repro.obs.export`  — Chrome/Perfetto ``trace.json`` writer
@@ -51,4 +53,4 @@ from .report import (  # noqa: F401
     analyze_trace,
     attribute_energy,
 )
-from .trace import NULL_TRACER, TraceEvent, Tracer  # noqa: F401
+from .trace import NULL_TRACER, Span, TraceEvent, Tracer  # noqa: F401

@@ -20,7 +20,9 @@ Design constraints, in order:
 
 Clock: ``time.perf_counter()`` (monotonic, sub-µs). All timestamps and
 durations are raw seconds on that clock; the Perfetto exporter converts
-to µs and normalizes to the earliest event.
+to µs and normalizes to the earliest event. A :class:`Span` also writes
+its block into a running ``jax.profiler`` trace, on the profiler's clock,
+beside the device ops it dispatched.
 
 Record phases mirror the Chrome trace-event format the exporter emits:
 ``"X"`` complete span (ts + dur), ``"i"`` instant, ``"C"`` counter
@@ -82,25 +84,56 @@ class _Ring:
         return out
 
 
-class _SpanCtx:
-    """Context-manager span for non-hot call sites (``with tracer.span``)."""
+_annotation = None       # jax.profiler.TraceAnnotation, bound on first use
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args):
-        self._tracer = tracer
+def _profiler_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class Span:
+    """A span in the profiler's trace and, where ``tracer`` is enabled, in
+    its ring: the one way this repo opens a span around a block.
+
+    The profiler half is a ``jax.profiler.TraceAnnotation`` (jax is
+    imported on first use, so ``repro.obs`` stays importable without it):
+    it lands in a ``jax.profiler`` trace on the clock the device ops use,
+    nested under any span open around it, and costs about 0.2 µs while no
+    trace runs. The ring half is a ``perf_counter`` complete span, as
+    :meth:`Tracer.complete` records it. :meth:`set` adds arguments known
+    only once the block has run (admitted request ids, tokens emitted)."""
+
+    __slots__ = ("_ann", "_tracer", "_name", "_cat", "_args", "_t0")
+
+    def __init__(self, name: str, tracer: "Tracer | None" = None,
+                 cat: str = "", args: Mapping[str, Any] | None = None):
+        self._ann = _profiler_annotation()(name, **(args or {}))
+        self._tracer = tracer if tracer is not None and tracer.enabled \
+            else None
         self._name = name
         self._cat = cat
         self._args = args
 
+    def set(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        if self._tracer is not None:
+            self._args = {**(self._args or {}), **args}
+
     def __enter__(self):
         self._t0 = time.perf_counter()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer.complete(self._name, self._t0, t1 - self._t0,
-                              cat=self._cat, args=self._args)
+        self._ann.__exit__(*exc)
+        if self._tracer is not None:
+            t1 = time.perf_counter()
+            self._tracer.complete(self._name, self._t0, t1 - self._t0,
+                                  cat=self._cat, args=self._args)
         return False
 
 
@@ -151,9 +184,10 @@ class Tracer:
         self._ring().append(("X", name, ts, dur, cat, args))
 
     def span(self, name: str, cat: str = "",
-             args: Mapping[str, Any] | None = None) -> _SpanCtx:
-        """``with tracer.span("name"): ...`` — times the block."""
-        return _SpanCtx(self, name, cat, args)
+             args: Mapping[str, Any] | None = None) -> Span:
+        """``with tracer.span("name"): ...`` — times the block into this
+        tracer's ring and the profiler's trace (:class:`Span`)."""
+        return Span(name, self, cat, args)
 
     def instant(self, name: str, cat: str = "",
                 args: Mapping[str, Any] | None = None,

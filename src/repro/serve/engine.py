@@ -32,16 +32,24 @@ Clocks: by default the engine runs on the wall clock (deadlines in
 step advances it by ``step_time_s`` exactly — the deterministic sim
 clock the serving scenarios and SLO property tests run on.
 
-Observability (both optional, duck-typed from ``repro.obs``): a
-``tracer`` records one ``serve/step`` span per engine step plus
-``serve/active_slots`` / ``serve/queue_depth`` counter tracks; a
-``metrics`` registry accumulates the serving-SLO quantities — the
-``serve/step_s`` latency histogram (p50/p95/p99 per window via
+Observability: each step is a ``serve/step`` span holding, in order,
+``serve/admit`` (queue scan and lane resets; ``rid`` lists the admitted
+request ids), ``serve/prepare`` (the token vector, moved to the device),
+``serve/dispatch`` (enqueueing the jitted ``decode_step``),
+``serve/sync`` (waiting for its tokens) and ``serve/emit`` (appending
+tokens, retiring requests, the records below). The spans are
+:class:`repro.obs.Span` s: they land in any running ``jax.profiler``
+trace on the profiler's clock, the one the device ops use, whether or
+not a tracer is given (``docs/serving.md`` has the command that shows
+them). Both further sinks are optional and duck-typed from ``repro.obs``:
+a ``tracer`` also gets the spans in its ring, plus ``serve/active_slots``
+/ ``serve/queue_depth`` counter tracks; a ``metrics`` registry
+accumulates the serving-SLO quantities — the ``serve/step_s`` latency
+histogram (step start to tokens on the host; p50/p95/p99 per window via
 ``window_summary()``, the p99 the SLO governor steers on),
 ``serve/tokens`` and ``serve/requests_done`` counters for joules/token
-attribution, a ``serve/queue_depth`` gauge, and the
-``serve/deadline_miss`` / ``serve/rejected`` counters the scenario
-results reconcile against (``tests/test_obs.py``).
+attribution, and the ``serve/deadline_miss`` / ``serve/rejected``
+counters the scenario results reconcile against (``tests/test_obs.py``).
 """
 from __future__ import annotations
 
@@ -56,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.transformer import Model
+from repro.obs.trace import Span
 
 from .slo import AdmissionPlanner, step_need_s
 
@@ -236,18 +245,20 @@ class ServeEngine:
         best = self._planned_step_s()
         return now + req.total_steps * best > req.deadline_s + 1e-12
 
-    def _admit(self) -> None:
+    def _admit(self) -> list[Request]:
+        """Move queued requests into free lanes; returns those admitted."""
         if self.admit_mode == "step0" and \
                 any(s is not None for s in self.slots):
-            return          # legacy batch mode: refill only when drained
+            return []       # legacy batch mode: refill only when drained
         now = self.now()
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not free:
-            return
+            return []
         # FIFO scan with skip: a head whose deadline needs a faster plan
         # than the current mix allows must not starve later requests that
         # fit — it stays queued until feasible or expired
         kept: deque[Request] = deque()
+        admitted = []
         while self.queue and free:
             req = self.queue.popleft()
             if self._expired(req, now):
@@ -261,34 +272,53 @@ class ServeEngine:
             self.slots[i] = req
             self._pending[i] = list(req.prompt)
             req.admitted_s = now
+            admitted.append(req)
         kept.extend(self.queue)
         self.queue = kept
+        return admitted
 
     # ----------------------------------------------------------------- step
     def step(self) -> None:
-        """One engine step = one decode_step over the slot batch."""
-        t0 = time.perf_counter()
-        self._admit()
-        active = sum(1 for s in self.slots if s is not None)
-        tokens = np.zeros((self.B,), np.int32)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            if self._pending[i]:
-                tokens[i] = self._pending[i].pop(0)
-            elif req.out:
-                tokens[i] = req.out[-1]
-            else:
-                tokens[i] = req.prompt[-1]
-        nxt, self.cache = self._step(self.params, self.cache,
-                                     jnp.asarray(tokens))
-        nxt = np.asarray(nxt)
-        t1 = time.perf_counter()
+        """One engine step = one decode_step over the slot batch, as the
+        spans ``serve/admit``, ``serve/prepare``, ``serve/dispatch``,
+        ``serve/sync`` and ``serve/emit`` inside one ``serve/step``."""
+        tracer = self.tracer
+        with Span("serve/step", tracer, "serve") as step_span:
+            t0 = time.perf_counter()
+            with Span("serve/admit", tracer, "serve") as sp:
+                admitted = self._admit()
+                if admitted:
+                    sp.set(rid=" ".join(str(r.rid) for r in admitted))
+            with Span("serve/prepare", tracer, "serve"):
+                active = sum(1 for s in self.slots if s is not None)
+                tokens = np.zeros((self.B,), np.int32)
+                for i, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    if self._pending[i]:
+                        tokens[i] = self._pending[i].pop(0)
+                    elif req.out:
+                        tokens[i] = req.out[-1]
+                    else:
+                        tokens[i] = req.prompt[-1]
+                tokens = jnp.asarray(tokens)
+            with Span("serve/dispatch", tracer, "serve"):
+                nxt, self.cache = self._step(self.params, self.cache, tokens)
+            with Span("serve/sync", tracer, "serve"):
+                nxt = np.asarray(nxt)
+            t1 = time.perf_counter()
+            with Span("serve/emit", tracer, "serve"):
+                emitted = self._emit(nxt, active, t1 - t0)
+            step_span.set(active=active, tokens=emitted)
+
+    def _emit(self, nxt: np.ndarray, active: int, host_s: float) -> int:
+        """Append the step's tokens, retire finished requests and record
+        the step; returns the tokens emitted."""
         if self.clock is not None:
             dt = self._planned_step_s()
             self.clock.advance(dt)
         else:
-            dt = t1 - t0
+            dt = host_s
         self.last_step_s = dt
         now = self.now()
         emitted = completed = missed = 0
@@ -310,8 +340,6 @@ class ServeEngine:
                 self.slots[i] = None
         tracer = self.tracer
         if tracer is not None and tracer.enabled:
-            tracer.complete("serve/step", t0, t1 - t0, cat="serve",
-                            args={"active": active, "tokens": emitted})
             tracer.counter("serve/active_slots", active)
             tracer.counter("serve/queue_depth", len(self.queue))
             if missed:
@@ -320,13 +348,13 @@ class ServeEngine:
         metrics = self.metrics
         if metrics is not None:
             metrics.observe("serve/step_s", dt)
-            metrics.set_gauge("serve/queue_depth", float(len(self.queue)))
             if emitted:
                 metrics.inc("serve/tokens", emitted)
             if completed:
                 metrics.inc("serve/requests_done", completed)
             if missed:
                 metrics.inc("serve/deadline_miss", missed)
+        return emitted
 
     def run_until_idle(self, max_steps: int = 10_000) -> None:
         """Step until the queue and every slot are empty. Waiting requests

@@ -426,7 +426,8 @@ def test_served_scenario_metrics_and_trace_round_trip(tmp_path):
     assert metrics.counter("serve/tokens") == res.tokens
     assert res.completed + res.rejected == len(res.requests)
     assert sum(w.completed for w in res.windows) <= res.completed
-    assert metrics.gauge("serve/queue_depth") is not None
+    # the backlog is a tracer counter track only (asserted below)
+    assert metrics.gauge("serve/queue_depth") is None
 
     # each window's p99 is the previous window's paced step time — the
     # deterministic sim makes the histogram round trip exact

@@ -113,6 +113,10 @@ def test_slot_reuse_resets_lane():
     assert b.out == expected_b
 
 
+PHASES = ("serve/admit", "serve/prepare", "serve/dispatch", "serve/sync",
+          "serve/emit")
+
+
 def test_engine_emits_trace_and_metrics():
     from repro.obs import MetricsRegistry, Tracer
 
@@ -132,6 +136,16 @@ def test_engine_emits_trace_and_metrics():
     steps = [e for e in events if e.name == "serve/step"]
     assert steps and all(e.ph == "X" and e.cat == "serve" for e in steps)
     assert steps[0].args["active"] == 2
+    # the ring holds the phase spans the profiler gets, in order, in each
+    # step; the first step admitted both requests
+    phases = [e for e in events if e.ph == "X" and e.name in PHASES]
+    for st in steps:
+        inside = [e for e in phases
+                  if st.ts <= e.ts and e.ts + e.dur <= st.ts + st.dur]
+        assert [e.name for e in inside] == list(PHASES)
+        assert all(e.cat == "serve" for e in inside)
+    assert len(phases) == len(PHASES) * len(steps)
+    assert phases[0].args == {"rid": "0 1"}
     assert any(e.name == "serve/active_slots" for e in events)
     assert metrics.counter("serve/tokens") == 6
     assert metrics.counter("serve/requests_done") == 2
