@@ -205,15 +205,25 @@ def context_attention(q, k, v, *, causal=True, window=0) -> jax.Array:
 def decode_attention_local(q, k_cache, v_cache, *, pos, window=0,
                            kv_offset=0) -> jax.Array:
     """Single-token attention over a cache: q (B, Hq, D), cache
-    (B, S, Hkv, D), ``pos`` = current absolute position (traced) — a
-    scalar, or a (B,) vector of per-slot positions (continuous batching:
-    each lane masks against its own progress)."""
+    (B, S, Hkv·D) with each position's heads side by side, ``pos`` =
+    current absolute position (traced) — a scalar, or a (B,) vector of
+    per-slot positions (continuous batching: each lane masks against its
+    own progress).
+
+    The cache is read as it lies: splitting its heads apart would relayout
+    it whenever D is no multiple of the device's 128-lane tile. q is spread
+    block-diagonally instead, row h·G+j holding query head h·G+j in the D
+    columns of KV head h and zeros elsewhere, so one contraction over the
+    merged axis scores every head, and the diagonal blocks of the value
+    contraction are the heads' outputs."""
     b, hq, d = q.shape
-    skv, n_kv = k_cache.shape[1], k_cache.shape[2]
+    skv, n_kv = k_cache.shape[1], k_cache.shape[2] // d
     g = hq // n_kv
+    eye = jnp.eye(n_kv, dtype=jnp.float32)
     qg = q.reshape(b, n_kv, g, d).astype(jnp.float32)
+    qbd = jnp.einsum("bhgd,hk->bhgkd", qg, eye).reshape(b, n_kv, g, -1)
     scale = 1.0 / math.sqrt(d)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache.astype(jnp.float32)) * scale
+    s = jnp.einsum("bhgc,bkc->bhgk", qbd, k_cache.astype(jnp.float32)) * scale
     kv_pos = kv_offset + jnp.arange(skv)
     pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
     msk = kv_pos[None, :] <= pos_b[:, None]                 # (B, Skv)
@@ -223,18 +233,19 @@ def decode_attention_local(q, k_cache, v_cache, *, pos, window=0,
     m = s.max(axis=-1)
     p = jnp.where(msk[:, None, None, :], jnp.exp(s - m[..., None]), 0.0)
     l = p.sum(axis=-1)
-    o = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache.astype(jnp.float32))
+    r = jnp.einsum("bhgk,bkc->bhgc", p, v_cache.astype(jnp.float32))
+    o = jnp.einsum("bhgkd,hk->bhgd", r.reshape(b, n_kv, g, n_kv, d), eye)
     return (o / jnp.maximum(l, 1e-30)[..., None], m, l)
 
 
 def decode_attention(q, k_cache, v_cache, *, pos, window=0) -> jax.Array:
     """Flash-decoding: cache sequence-sharded over 'model', LSE-combined via
-    psum — architecture-independent of head counts. q: (B, Hq, D)."""
+    psum — architecture-independent of head counts. q: (B, Hq, D), cache
+    (B, S, Hkv·D) as :func:`decode_attention_local` takes it."""
     ctx = current_ctx()
     mesh = ctx.mesh
     b, hq, d = q.shape
-    skv, n_kv = k_cache.shape[1], k_cache.shape[2]
-    g = hq // n_kv
+    skv = k_cache.shape[1]
 
     axes = ctx.mesh_axes("kv_seq")
     if mesh is None or not axes or skv % ctx.axes_size("kv_seq") != 0:
@@ -250,7 +261,7 @@ def decode_attention(q, k_cache, v_cache, *, pos, window=0) -> jax.Array:
         used = set(bspec if isinstance(bspec, tuple) else (bspec,))
         axes = tuple(a for a in axes if a not in used) or axes
     qspec = P(bspec, None, None)
-    cspec = P(bspec, axes if len(axes) > 1 else axes[0], None, None)
+    cspec = P(bspec, axes if len(axes) > 1 else axes[0], None)
     # per-slot pos vectors shard with the batch; scalar pos is replicated
     pspec = P(bspec) if jnp.ndim(pos) else P()
 

@@ -479,20 +479,20 @@ class Model:
         return rms_norm(h, params["ln_enc_final"], c.norm_eps)
 
     def cross_kv(self, params: Params, enc_out: jax.Array):
-        """Per-decoder-layer cross-attention K/V from encoder states."""
-        c = self.cfg
-        b, t, _ = enc_out.shape
-        k = jnp.einsum("btd,lde->lbte", enc_out,
-                       params["dec"]["cwk"]).reshape(
-            c.n_layers, b, t, c.n_kv_heads, c.hd)
-        v = jnp.einsum("btd,lde->lbte", enc_out,
-                       params["dec"]["cwv"]).reshape(
-            c.n_layers, b, t, c.n_kv_heads, c.hd)
+        """Per-decoder-layer cross-attention K/V from encoder states, each
+        (L, B, T, Hkv·hd) as the decode cache holds them."""
+        k = jnp.einsum("btd,lde->lbte", enc_out, params["dec"]["cwk"])
+        v = jnp.einsum("btd,lde->lbte", enc_out, params["dec"]["cwv"])
         return k, v
 
     def init_cache(self, batch_size: int, seq_len: int, abstract: bool = False,
                    params: Params | None = None, batch: dict | None = None):
         """Zeroed (or abstract) decode cache for a max context of seq_len.
+
+        A KV leaf is (..., B, S, Hkv·hd): each position's heads side by
+        side, so the device's default layout tiles it unpadded and the
+        decode step reads and writes it in place (see
+        :func:`~repro.models.attention.decode_attention_local`).
 
         For encoder-decoder models, pass ``params`` and a ``batch`` with
         'frames' to populate the cross-attention K/V from the encoder."""
@@ -501,7 +501,8 @@ class Model:
         make = (lambda sh, dt=cdt: jax.ShapeDtypeStruct(sh, dt)) if abstract \
             else (lambda sh, dt=cdt: jnp.zeros(sh, dt))
         b = batch_size
-        kvshape = lambda n, s: (n, b, s, c.n_kv_heads, c.hd)  # noqa: E731
+        kvd = c.n_kv_heads * c.hd
+        kvshape = lambda n, s: (n, b, s, kvd)  # noqa: E731
         # per-slot positions: each batch lane advances independently, so a
         # serving engine can admit a request mid-run by resetting one lane
         cache: dict[str, Any] = {"pos": make((b,), jnp.int32)}
@@ -511,8 +512,8 @@ class Model:
         elif c.window > 0:
             ns, nt, per = self.n_super, self.n_tail, c.global_every
             w = min(c.window, seq_len)
-            cache["k_local"] = make((ns, per - 1, b, w, c.n_kv_heads, c.hd))
-            cache["v_local"] = make((ns, per - 1, b, w, c.n_kv_heads, c.hd))
+            cache["k_local"] = make((ns, per - 1, b, w, kvd))
+            cache["v_local"] = make((ns, per - 1, b, w, kvd))
             cache["k_global"] = make(kvshape(ns, seq_len))
             cache["v_global"] = make(kvshape(ns, seq_len))
             if nt:
@@ -555,13 +556,13 @@ class Model:
         """Logical axes for the cache pytree (kv seq axis sharded)."""
         c = self.cfg
         ax: dict[str, Any] = {"pos": ("batch",)}
-        kv = (None, "batch", "kv_seq", None, None)
+        kv = (None, "batch", "kv_seq", None)
         if c.kind in ("dense", "moe", "vlm") and c.window <= 0:
             ax["k"] = kv
             ax["v"] = kv
         elif c.window > 0:
-            ax["k_local"] = (None, None, "batch", "kv_seq", None, None)
-            ax["v_local"] = (None, None, "batch", "kv_seq", None, None)
+            ax["k_local"] = (None, None, "batch", "kv_seq", None)
+            ax["v_local"] = (None, None, "batch", "kv_seq", None)
             ax["k_global"] = kv
             ax["v_global"] = kv
             if self.n_tail:
@@ -606,44 +607,50 @@ class Model:
             new[key] = val.at[idx].set(jnp.zeros((), val.dtype))
         return new
 
-    def _attn_decode(self, p, x, cache_kv, pos, *, rolling=False, window=0,
+    def _attn_decode(self, p, x, cache_kv, pos, *, layer=None, rolling=False,
                      prefix="", cross=False):
-        """x (B, 1, D); cache_kv = (k, v) slices (B, S, Hkv, hd).
+        """Attention of one decode token: x (B, 1, D); cache_kv = (k, v),
+        each (B, S, Hkv·hd), or with ``layer`` the whole stacks
+        (L, B, S, Hkv·hd) carried through the layer scan, read and written
+        at ``[layer]`` in place: a stack the scan took in as per-layer
+        slices and stacked back out would be copied whole every step.
 
-        Returns (x', (k_cache', v_cache')). For cross attention the cache is
-        read-only."""
+        The new token's K/V are written at each lane's own position
+        (``pos % S`` for a rolling window). Returns (x', (k', v')). For
+        cross attention the cache is read-only."""
         c = self.cfg
         b = x.shape[0]
         k_cache, v_cache = cache_kv
+        at = () if layer is None else (layer,)
+        s_len = k_cache.shape[-2]
         with jax.named_scope("attn"):
             h = rms_norm(x, p[prefix + "ln_attn"], c.norm_eps)
             q = (h @ p[prefix + "wq"]).reshape(b, 1, c.n_heads, c.hd)
             if not cross:
                 k = (h @ p[prefix + "wk"]).reshape(b, 1, c.n_kv_heads, c.hd)
-                v = (h @ p[prefix + "wv"]).reshape(b, 1, c.n_kv_heads, c.hd)
+                v = h @ p[prefix + "wv"]
                 # pos is per-slot (B,): each lane rotates and writes at its
                 # own position, so mid-run admissions decode exactly as if
                 # solo
                 pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
                 sin, cos = rope_table(pos_b[:, None], c.hd, c.rope_theta)
                 q = apply_rope(q, sin, cos)
-                k = apply_rope(k, sin, cos)
+                k = apply_rope(k, sin, cos).reshape(b, 1, -1)
         if not cross:
             with jax.named_scope("kv_write"):
                 if rolling:
-                    slot = pos_b % k_cache.shape[1]
+                    slot = pos_b % s_len
                 else:
-                    slot = jnp.minimum(pos_b, k_cache.shape[1] - 1)
-                k_cache = k_cache.at[jnp.arange(b), slot].set(
-                    k[:, 0].astype(k_cache.dtype))
-                v_cache = v_cache.at[jnp.arange(b), slot].set(
-                    v[:, 0].astype(v_cache.dtype))
+                    slot = jnp.minimum(pos_b, s_len - 1)
+                idx = at + (jnp.arange(b), slot)
+                k_cache = k_cache.at[idx].set(k[:, 0].astype(k_cache.dtype))
+                v_cache = v_cache.at[idx].set(v[:, 0].astype(v_cache.dtype))
             att_pos = pos_b
         else:
-            att_pos = jnp.int32(k_cache.shape[1] - 1)  # attend to all enc kv
+            att_pos = jnp.int32(s_len - 1)  # attend to all enc kv
         with jax.named_scope("attn"):
-            o = decode_attention(q[:, 0], k_cache, v_cache, pos=att_pos,
-                                 window=0 if rolling or cross else window)
+            o = decode_attention(q[:, 0], k_cache[at], v_cache[at],
+                                 pos=att_pos)
             o = o.reshape(b, 1, -1) @ p[prefix + "wo"]
             return x + o, (k_cache, v_cache)
 
@@ -665,15 +672,17 @@ class Model:
         newc = dict(cache)
 
         if c.kind in ("dense", "moe", "vlm") and c.window <= 0:
-            def body(xx, xs):
-                p, kc, vc = xs
+            def body(carry, xs):
+                xx, kv = carry
+                p, i = xs
                 with jax.named_scope("layer"):
-                    xx, (kc, vc) = self._attn_decode(p, xx, (kc, vc), pos)
+                    xx, kv = self._attn_decode(p, xx, kv, pos, layer=i)
                     with jax.named_scope("ffn"):
                         xx = self._ffn(p, xx)
-                return xx, (kc, vc)
-            x, (newc["k"], newc["v"]) = _scan(
-                body, x, (params["layers"], cache["k"], cache["v"]))
+                return (xx, kv), None
+            (x, (newc["k"], newc["v"])), _ = _scan(
+                body, (x, (cache["k"], cache["v"])),
+                (params["layers"], jnp.arange(c.n_layers)))
         elif c.window > 0:
             x = self._decode_windowed(params, x, cache, newc, pos)
         elif c.kind == "ssm":
@@ -690,18 +699,20 @@ class Model:
         elif c.kind == "hybrid":
             x = self._decode_hybrid(params, x, cache, newc, pos)
         elif c.kind in ("encdec", "audio"):
-            def body(xx, xs):
-                p, ks, vs, kc, vc = xs
+            def body(carry, xs):
+                xx, kv = carry
+                p, kc, vc, i = xs
                 with jax.named_scope("layer"):
-                    xx, (ks, vs) = self._attn_decode(p, xx, (ks, vs), pos)
+                    xx, kv = self._attn_decode(p, xx, kv, pos, layer=i)
                     xx, _ = self._attn_decode(p, xx, (kc, vc), pos,
                                               prefix="c", cross=True)
                     with jax.named_scope("ffn"):
                         xx = self._ffn(p, xx)
-                return xx, (ks, vs)
-            x, (newc["k_self"], newc["v_self"]) = _scan(
-                body, x, (params["dec"], cache["k_self"], cache["v_self"],
-                          cache["k_cross"], cache["v_cross"]))
+                return (xx, kv), None
+            (x, (newc["k_self"], newc["v_self"])), _ = _scan(
+                body, (x, (cache["k_self"], cache["v_self"])),
+                (params["dec"], cache["k_cross"], cache["v_cross"],
+                 jnp.arange(c.n_layers)))
         with jax.named_scope("head"):
             x = rms_norm(x, params["ln_final"], c.norm_eps)
             nxt = embedloss.greedy(x[:, 0], params["embed"],
@@ -721,21 +732,23 @@ class Model:
                     xx = self._ffn(p, xx)
             return xx, (kc, vc)
 
-        def super_body(xx, xs):
-            p, kl, vl, kg, vg = xs
+        def super_body(carry, xs):
+            xx, kvg = carry
+            p, kl, vl, i = xs
             xx, (kl, vl) = _scan(local_body, xx, (p["local"], kl, vl))
             with jax.named_scope("layer"):
-                xx, (kg, vg) = self._attn_decode(p["global"], xx, (kg, vg),
-                                                 pos)
+                xx, kvg = self._attn_decode(p["global"], xx, kvg, pos,
+                                            layer=i)
                 with jax.named_scope("ffn"):
                     xx = self._ffn(p["global"], xx)
-            return xx, (kl, vl, kg, vg)
+            return (xx, kvg), (kl, vl)
 
         stacked = {"local": params["local"], "global": params["global"]}
-        x, (newc["k_local"], newc["v_local"], newc["k_global"],
-            newc["v_global"]) = _scan(
-            super_body, x, (stacked, cache["k_local"], cache["v_local"],
-                            cache["k_global"], cache["v_global"]))
+        (x, (newc["k_global"], newc["v_global"])), (
+            newc["k_local"], newc["v_local"]) = _scan(
+            super_body, (x, (cache["k_global"], cache["v_global"])),
+            (stacked, cache["k_local"], cache["v_local"],
+             jnp.arange(self.n_super)))
         if self.n_tail:
             x, (newc["k_tail"], newc["v_tail"]) = _scan(
                 local_body, x, (params["tail"], cache["k_tail"],
@@ -755,19 +768,21 @@ class Model:
                 xx = xx + y
             return xx, (conv, st)
 
-        def super_body(xx, xs):
-            p, conv, st, ks, vs = xs
+        def super_body(carry, xs):
+            xx, kv = carry
+            p, conv, st, i = xs
             xx, (conv, st) = _scan(mamba_body, xx, (p, conv, st))
             with jax.named_scope("layer"):
-                xx, (ks, vs) = self._attn_decode(shared, xx, (ks, vs), pos)
+                xx, kv = self._attn_decode(shared, xx, kv, pos, layer=i)
                 with jax.named_scope("ffn"):
                     xx = self._ffn(shared, xx)
-            return xx, (conv, st, ks, vs)
+            return (xx, kv), (conv, st)
 
-        x, (newc["conv"], newc["state"], newc["k_shared"],
-            newc["v_shared"]) = _scan(
-            super_body, x, (params["mamba"], cache["conv"], cache["state"],
-                            cache["k_shared"], cache["v_shared"]))
+        (x, (newc["k_shared"], newc["v_shared"])), (
+            newc["conv"], newc["state"]) = _scan(
+            super_body, (x, (cache["k_shared"], cache["v_shared"])),
+            (params["mamba"], cache["conv"], cache["state"],
+             jnp.arange(self.n_super)))
         if self.n_tail:
             x, (newc["conv_tail"], newc["state_tail"]) = _scan(
                 mamba_body, x, (params["tail"], cache["conv_tail"],
@@ -789,24 +804,27 @@ class Model:
         cache["pos"] = jnp.full((b,), s, jnp.int32)
 
         def place_full(dst, src):
-            # src (..., B, S, Hkv, hd) -> write into dst (..., B, Smax, ...)
+            # src (..., B, S, Hkv·hd) -> write into dst (..., B, Smax, ...)
             return jax.lax.dynamic_update_slice_in_dim(
-                dst, src.astype(dst.dtype), 0, axis=src.ndim - 3)
+                dst, src.astype(dst.dtype), 0, axis=src.ndim - 2)
 
         def place_rolling(dst, src, window):
             # keep the last `window` positions arranged so slot = pos % window
             if s <= window:
                 return jax.lax.dynamic_update_slice_in_dim(
-                    dst, src.astype(dst.dtype), 0, axis=src.ndim - 3)
-            last = jax.lax.slice_in_dim(src, s - window, s, axis=src.ndim - 3)
-            return jnp.roll(last, s % window, axis=src.ndim - 3).astype(
+                    dst, src.astype(dst.dtype), 0, axis=src.ndim - 2)
+            last = jax.lax.slice_in_dim(src, s - window, s, axis=src.ndim - 2)
+            return jnp.roll(last, s % window, axis=src.ndim - 2).astype(
                 dst.dtype)
 
         for key, src in col.items():
             if key in ("conv", "state", "conv_tail", "state_tail"):
                 cache[key] = src.astype(cache[key].dtype)
-            elif key in ("k_local", "v_local", "k_tail", "v_tail"):
-                w = cache[key].shape[-3]
+                continue
+            # the forward's (..., B, S, Hkv, hd), heads merged
+            src = src.reshape(src.shape[:-2] + (-1,))
+            if key in ("k_local", "v_local", "k_tail", "v_tail"):
+                w = cache[key].shape[-2]
                 cache[key] = place_rolling(cache[key], src, w)
             else:
                 cache[key] = place_full(cache[key], src)

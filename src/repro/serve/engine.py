@@ -48,8 +48,10 @@ accumulates the serving-SLO quantities — the ``serve/step_s`` latency
 histogram (step start to tokens on the host; p50/p95/p99 per window via
 ``window_summary()``, the p99 the SLO governor steers on),
 ``serve/tokens`` and ``serve/requests_done`` counters for joules/token
-attribution, and the ``serve/deadline_miss`` / ``serve/rejected``
-counters the scenario results reconcile against (``tests/test_obs.py``).
+attribution, the ``serve/deadline_miss`` / ``serve/rejected``
+counters the scenario results reconcile against (``tests/test_obs.py``),
+and the ``serve/cache_bytes`` gauge: the decode cache's bytes on the
+device, one buffer that each step updates in place.
 """
 from __future__ import annotations
 
@@ -133,6 +135,10 @@ class ServeEngine:
         self.plan_point = None          # the planner's latest selection
         self.plan_feasible = True       # False: running the EAPS fallback
         self.cache = model.init_cache(batch_slots, max_len)
+        if metrics is not None:
+            metrics.set_gauge("serve/cache_bytes", sum(
+                a.on_device_size_in_bytes()
+                for a in jax.tree.leaves(self.cache)))
         self.queue: deque[Request] = deque()
         self.rejected: list[Request] = []
         self.slots: list[Optional[Request]] = [None] * batch_slots

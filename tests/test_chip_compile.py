@@ -9,6 +9,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,6 +25,9 @@ from repro.models.transformer import Model
 # one v5e chip has 16 GiB of HBM; leave room for what the process holds
 # besides the step program
 DECODE_STEP_BUDGET_BYTES = 14e9
+# the served step updates its cache in place: what it needs besides its
+# arguments is a few activations, not a copy of the cache
+SERVED_STEP_TEMP_BYTES = 64 << 20
 
 
 @pytest.fixture(scope="module")
@@ -102,3 +107,44 @@ def test_stablelm_decode_step_fits_one_chip(one_chip):
         f"decode step needs {used / 1e9:.2f} GB "
         f"(args {mem.argument_size_in_bytes / 1e9:.2f} GB, "
         f"temps {mem.temp_size_in_bytes / 1e9:.2f} GB)")
+
+
+def _cache_moves(hlo: str, leaf) -> list[str]:
+    """HLO instructions, fused ones included, that move a whole cache leaf
+    or one layer of it: a ``copy`` of that shape, or a
+    ``dynamic-update-slice`` whose update has it. A one-layer shape is
+    counted with and without its layer axis. A dynamic-update-slice that
+    writes a token or a lane into the cache in place is not a move."""
+    dims = [leaf.shape, (1,) + leaf.shape[1:], leaf.shape[1:]]
+    dt = jnp.dtype(leaf.dtype).name.replace("bfloat16", "bf16")
+    moved = {f"{dt}[{','.join(map(str, d))}]" for d in dims}
+    inst = re.compile(r"%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(%([^,)\s]+)"
+                      r"(?:, %([^,)\s]+))?")
+    found = [m for m in map(inst.search, hlo.splitlines()) if m]
+    shape = {m[1]: m[2] for m in found}
+    return [f"{m[3]} {m[1]}" for m in found
+            if m[3] == "copy" and m[2] in moved
+            or m[3] == "dynamic-update-slice" and shape.get(m[5]) in moved]
+
+
+def test_stablelm_served_step_updates_cache_in_place(one_chip):
+    """The step and the lane reset as the engine jits them, with the cache
+    in the device's default layout: the cache stays one buffer that each
+    layer writes a token into, neither copied nor relaid out."""
+    model = Model(get_config("stablelm-3b"))
+    place = lambda s: _spec(s.shape, s.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(place, model.abstract_params())
+    cache = jax.tree.map(place, model.init_cache(8, 1024, abstract=True))
+    step = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, _spec((8,), jnp.int32, one_chip)).compile()
+    reset_lane = jax.jit(model.reset_cache_lane, donate_argnums=(0,)).lower(
+        cache, _spec((), jnp.int32, one_chip)).compile()
+    mem = step.memory_analysis()
+    assert mem.temp_size_in_bytes < SERVED_STEP_TEMP_BYTES, (
+        f"served step temporaries {mem.temp_size_in_bytes} B")
+    assert step.output_formats[1] == step.input_formats[0][1]
+    for name, program in (("step", step), ("reset_cache_lane", reset_lane)):
+        hlo = program.as_text()
+        for key in ("k", "v"):
+            moves = _cache_moves(hlo, cache[key])
+            assert not moves, f"{name} moves cache {key!r}: {moves}"

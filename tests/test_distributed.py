@@ -52,8 +52,8 @@ assert float(jnp.abs(outw-refw).max()) < 1e-5, "window context_attention"
 ok.append("window_context_attention")
 
 # ---- flash decoding (cache seq-sharded over model) ----
-kc = jnp.asarray(rng.normal(size=(B, 32, Hkv, D)), jnp.float32)
-vc = jnp.asarray(rng.normal(size=(B, 32, Hkv, D)), jnp.float32)
+kc = jnp.asarray(rng.normal(size=(B, 32, Hkv * D)), jnp.float32)
+vc = jnp.asarray(rng.normal(size=(B, 32, Hkv * D)), jnp.float32)
 qd = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
 pos = jnp.int32(19)
 o_ref, _, _ = decode_attention_local(qd, kc, vc, pos=pos)

@@ -151,3 +151,8 @@ def test_engine_emits_trace_and_metrics():
     assert metrics.counter("serve/requests_done") == 2
     hist = metrics.snapshot()["histograms"]["serve/step_s"]
     assert hist["count"] == len(steps) and hist["p99"] > 0
+    # the cache's bytes on the device, in its layout there
+    assert metrics.gauge("serve/cache_bytes") == sum(
+        a.on_device_size_in_bytes() for a in jax.tree.leaves(engine.cache))
+    assert metrics.gauge("serve/cache_bytes") >= sum(
+        a.nbytes for a in jax.tree.leaves(engine.cache))
