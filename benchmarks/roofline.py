@@ -56,8 +56,8 @@ def _extrapolate(pts: list[dict], cfg) -> dict:
     """Reconstruct full-depth costs from shallow points (linear in depth)."""
     by_layers = {p["n_layers"]: _fields(p) for p in pts}
     Ls = sorted(by_layers)
-    if cfg.window > 0 or (cfg.kind == "hybrid" and cfg.shared_attn_every):
-        per = cfg.global_every if cfg.window > 0 else cfg.shared_attn_every
+    if cfg.layer_period:
+        per = cfg.layer_period
         tail = cfg.n_layers % per
         n_super = cfg.n_layers // per
         c1, c2 = by_layers[per], by_layers[2 * per]

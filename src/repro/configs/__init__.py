@@ -4,6 +4,7 @@ from repro.configs import (  # noqa: F401
     arctic_480b,
     gemma3_12b,
     gemma3_1b,
+    granite_4_0_h_micro,
     internvl2_26b,
     kimi_k2_1t,
     mamba2_1_3b,

@@ -149,8 +149,8 @@ def analysis_points(cfg) -> list[tuple[str, object]]:
     import dataclasses as _dc
 
     pts = []
-    if cfg.window > 0 or (cfg.kind == "hybrid" and cfg.shared_attn_every):
-        per = cfg.global_every if cfg.window > 0 else cfg.shared_attn_every
+    if cfg.layer_period:
+        per = cfg.layer_period
         tail = cfg.n_layers % per
         pts.append((f"L{per}", _dc.replace(cfg, n_layers=per)))
         pts.append((f"L{2 * per}", _dc.replace(cfg, n_layers=2 * per)))

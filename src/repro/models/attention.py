@@ -48,6 +48,11 @@ def _ungroup(o: jax.Array) -> jax.Array:
     return o.transpose(0, 3, 1, 2, 4).reshape(b, s, n_kv * g, d)
 
 
+def _scale(scale, d: int) -> float:
+    """The scores' multiplier: ``scale``, or 1/sqrt(D) where it is None."""
+    return 1.0 / math.sqrt(d) if scale is None else scale
+
+
 def _mask(q_pos, kv_pos, causal: bool, window: int):
     """Boolean mask (..., Sq, Skv): True = attend."""
     m = jnp.ones(q_pos.shape + kv_pos.shape, dtype=bool)
@@ -60,11 +65,11 @@ def _mask(q_pos, kv_pos, causal: bool, window: int):
 
 # ------------------------------------------------------------------- naive
 def naive_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                    kv_offset=0) -> jax.Array:
+                    kv_offset=0, scale=None) -> jax.Array:
     b, sq, hq, d = q.shape
     skv, n_kv = k.shape[1], k.shape[2]
     qg = _group(q, n_kv)
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale(scale, d)
     s = jnp.einsum("bhgqd,bkhd->bhgqk", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     q_pos = q_offset + jnp.arange(sq)
@@ -78,10 +83,12 @@ def naive_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 
 # --------------------------------------------------------------- xla flash
 def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
-                        kv_offset=0, kv_chunk=512, kv_len=None) -> jax.Array:
+                        kv_offset=0, kv_chunk=512, kv_len=None,
+                        scale=None) -> jax.Array:
     """Memory-efficient attention: lax.scan over KV chunks, fp32 running
     softmax. ``q_offset``/``kv_offset`` may be traced (context parallelism).
     ``kv_len``: optional traced count of valid kv positions (decode caches).
+    ``scale`` multiplies the scores (None: 1/sqrt(D)).
     """
     b, sq, hq, d = q.shape
     skv, n_kv = k.shape[1], k.shape[2]
@@ -98,7 +105,7 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
         skv = skv + pad
         n_chunks += 1
     qg = _group(q, n_kv).astype(jnp.float32)  # (B, Hkv, G, Sq, D)
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale(scale, d)
     q_pos = q_offset + jnp.arange(sq)
 
     ks = k.reshape(b, n_chunks, kv_chunk, n_kv, d).transpose(1, 0, 3, 2, 4)
@@ -133,7 +140,8 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
     return _ungroup(o).astype(q.dtype)
 
 
-def window_attention_xla(q, k, v, *, window, q_offset=0, q_chunk=0) -> jax.Array:
+def window_attention_xla(q, k, v, *, window, q_offset=0, q_chunk=0,
+                         scale=None) -> jax.Array:
     """Sliding-window attention with per-q-chunk KV slicing: each query chunk
     only reads a (window + chunk)-sized KV slice, so HLO FLOPs are
     O(S·window) rather than O(S²). ``q_offset`` may be traced.
@@ -144,7 +152,7 @@ def window_attention_xla(q, k, v, *, window, q_offset=0, q_chunk=0) -> jax.Array
     span = window + q_chunk
     if span >= skv:
         return flash_attention_xla(q, k, v, causal=True, window=window,
-                                   q_offset=q_offset)
+                                   q_offset=q_offset, scale=scale)
     outs = []
     for a in range(0, sq, q_chunk):
         cq = min(q_chunk, sq - a)
@@ -157,13 +165,15 @@ def window_attention_xla(q, k, v, *, window, q_offset=0, q_chunk=0) -> jax.Array
             flash_attention_xla(
                 qc, kc, vc, causal=True, window=window,
                 q_offset=q_offset + a, kv_offset=start, kv_chunk=span,
+                scale=scale,
             )
         )
     return jnp.concatenate(outs, axis=1)
 
 
 # --------------------------------------------------- distributed (shard_map)
-def context_attention(q, k, v, *, causal=True, window=0) -> jax.Array:
+def context_attention(q, k, v, *, causal=True, window=0,
+                      scale=None) -> jax.Array:
     """All-gather-KV context parallelism over the 'model' axis.
 
     Queries stay sequence-sharded; each shard gathers the full KV for the
@@ -177,9 +187,10 @@ def context_attention(q, k, v, *, causal=True, window=0) -> jax.Array:
 
     def local(qq, kk, vv, q_off):
         if window > 0 and causal:
-            return window_attention_xla(qq, kk, vv, window=window, q_offset=q_off)
+            return window_attention_xla(qq, kk, vv, window=window,
+                                        q_offset=q_off, scale=scale)
         return flash_attention_xla(qq, kk, vv, causal=causal, window=window,
-                                   q_offset=q_off)
+                                   q_offset=q_off, scale=scale)
 
     axes = ctx.mesh_axes("seq")
     if mesh is None or not axes or sq % ctx.axes_size("seq") != 0:
@@ -203,7 +214,7 @@ def context_attention(q, k, v, *, causal=True, window=0) -> jax.Array:
 
 
 def decode_attention_local(q, k_cache, v_cache, *, pos, window=0,
-                           kv_offset=0) -> jax.Array:
+                           kv_offset=0, scale=None) -> jax.Array:
     """Single-token attention over a cache: q (B, Hq, D), cache
     (B, S, Hkv·D) with each position's heads side by side, ``pos`` =
     current absolute position (traced) — a scalar, or a (B,) vector of
@@ -222,7 +233,7 @@ def decode_attention_local(q, k_cache, v_cache, *, pos, window=0,
     eye = jnp.eye(n_kv, dtype=jnp.float32)
     qg = q.reshape(b, n_kv, g, d).astype(jnp.float32)
     qbd = jnp.einsum("bhgd,hk->bhgkd", qg, eye).reshape(b, n_kv, g, -1)
-    scale = 1.0 / math.sqrt(d)
+    scale = _scale(scale, d)
     s = jnp.einsum("bhgc,bkc->bhgk", qbd, k_cache.astype(jnp.float32)) * scale
     kv_pos = kv_offset + jnp.arange(skv)
     pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
@@ -238,7 +249,8 @@ def decode_attention_local(q, k_cache, v_cache, *, pos, window=0,
     return (o / jnp.maximum(l, 1e-30)[..., None], m, l)
 
 
-def decode_attention(q, k_cache, v_cache, *, pos, window=0) -> jax.Array:
+def decode_attention(q, k_cache, v_cache, *, pos, window=0,
+                     scale=None) -> jax.Array:
     """Flash-decoding: cache sequence-sharded over 'model', LSE-combined via
     psum — architecture-independent of head counts. q: (B, Hq, D), cache
     (B, S, Hkv·D) as :func:`decode_attention_local` takes it."""
@@ -250,7 +262,7 @@ def decode_attention(q, k_cache, v_cache, *, pos, window=0) -> jax.Array:
     axes = ctx.mesh_axes("kv_seq")
     if mesh is None or not axes or skv % ctx.axes_size("kv_seq") != 0:
         o, _, _ = decode_attention_local(q, k_cache, v_cache, pos=pos,
-                                         window=window)
+                                         window=window, scale=scale)
         return o.reshape(b, hq, d).astype(q.dtype)
     # kv_seq may map to several mesh axes (e.g. ('data', 'model') for the
     # batch-1 long-context cells, where the data axis would otherwise idle):
@@ -271,7 +283,7 @@ def decode_attention(q, k_cache, v_cache, *, pos, window=0) -> jax.Array:
             idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
         base = idx * kk.shape[1]
         o, m, l = decode_attention_local(qq, kk, vv, pos=pp, window=window,
-                                         kv_offset=base)
+                                         kv_offset=base, scale=scale)
         # o is per-shard *normalized* (acc / l): re-weight each shard's
         # contribution by exp(m - gm) * l before the global combine.
         gm = jax.lax.pmax(m, axes)

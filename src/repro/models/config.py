@@ -55,9 +55,15 @@ class ModelConfig:
     # use a `window`-token local attention (gemma3: 5 local : 1 global).
     window: int = 0                   # 0 -> full attention everywhere
     global_every: int = 6
-    # Hybrid (zamba2): mamba blocks with a shared attention block applied
-    # every `shared_attn_every` layers (weights shared across applications).
+    # Hybrid, zamba2 form: Mamba2 layers with one shared attention block
+    # (weights shared across applications, its own MLP) applied after every
+    # `shared_attn_every` layers.
     shared_attn_every: int = 0
+    # Hybrid, granite form: layer i is a GQA attention layer when
+    # i % attn_every == attn_offset and a Mamba2 layer otherwise; every
+    # layer is followed by its own MLP. n_layers is whole periods.
+    attn_every: int = 0
+    attn_offset: int = 0
     # Encoder-decoder (whisper): number of encoder layers; frontend stub emits
     # `enc_len` precomputed frame embeddings.
     n_enc_layers: int = 0
@@ -65,6 +71,15 @@ class ModelConfig:
     # VLM (internvl): first `n_patches` positions come from the vision stub.
     n_patches: int = 0
     rope_theta: float = 10_000.0
+    rope: bool = True                 # False: no position embedding (NoPE)
+    # Multipliers (granite): token embeddings are scaled by
+    # `embedding_multiplier`, each residual branch by `residual_multiplier`,
+    # attention scores by `attention_multiplier` (0 -> 1/sqrt(head_dim)),
+    # and logits are divided by `logits_scaling`.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     param_dtype: str = "bfloat16"
@@ -86,6 +101,27 @@ class ModelConfig:
         the embedding table shards evenly on the model axis; the loss and
         sampler mask the padding columns."""
         return ((self.vocab + 127) // 128) * 128
+
+    @property
+    def layer_period(self) -> int:
+        """Layers in one period of the layer pattern; 0 for a uniform
+        stack."""
+        if self.window > 0:
+            return self.global_every
+        if self.kind == "hybrid":
+            return self.shared_attn_every or self.attn_every
+        return 0
+
+    def layer_types(self) -> tuple[str, ...]:
+        """Each layer's mixer, ``"mamba"`` or ``"attention"``, in order (a
+        zamba2-form shared block is applied between layers, not one)."""
+        if self.kind == "ssm" or (self.kind == "hybrid"
+                                  and not self.attn_every):
+            return ("mamba",) * self.n_layers
+        if self.kind == "hybrid":
+            return tuple("attention" if i % self.attn_every == self.attn_offset
+                         else "mamba" for i in range(self.n_layers))
+        return ("attention",) * self.n_layers
 
     def is_global_layer(self, i: int) -> bool:
         if self.window <= 0:
@@ -125,6 +161,15 @@ class ModelConfig:
             total += self.n_layers * ssm_params()
             active = total
             return total, active
+
+        if self.kind == "hybrid" and self.attn_every:
+            kinds = self.layer_types()
+            n_attn = kinds.count("attention")
+            # the Mamba2 layers' MLP norm; attn_params counts both norms
+            total += n_attn * attn_params() \
+                + (len(kinds) - n_attn) * (ssm_params() + d) \
+                + len(kinds) * mlp_params(self.d_ff)
+            return total, total
 
         if self.kind == "hybrid":
             per = ssm_params()  # the MLP lives in the shared block only

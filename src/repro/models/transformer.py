@@ -9,8 +9,12 @@ Families (ModelConfig.kind):
                      sliding-window layers + 1 global layer, single outer
                      scan; rolling window KV caches for local layers.
   ssm              : Mamba2 (SSD) stack.
-  hybrid           : zamba2 — Mamba2 superblocks + one *shared* attention
-                     block applied every `shared_attn_every` layers.
+  hybrid           : one Mamba2 stack with attention applied after some of
+                     its layers, one body for two forms: zamba2 — one
+                     *shared* attention block after every
+                     `shared_attn_every` layers; granite — a GQA attention
+                     layer of its own at `attn_offset` of every
+                     `attn_every` layers, each layer with its own MLP.
   encdec / audio   : whisper — encoder (non-causal) + decoder with
                      cross-attention; frame embeddings from the frontend stub.
 
@@ -164,6 +168,25 @@ def _mamba_leaves(m: _Maker, cfg: ModelConfig, stack: tuple[int, ...],
         _mlp_leaves(m, cfg, stack)
 
 
+@dataclasses.dataclass(frozen=True)
+class HybridLayout:
+    """A hybrid's layers: one stack of ``n_mamba`` Mamba2 layers, with an
+    attention block applied after Mamba2 layer ``attn_after[k]`` for each
+    k. ``shared``: one attention block serves every application and the
+    Mamba2 layers have no MLP (zamba2); else application k has attention
+    weights of its own and every layer its own MLP (granite)."""
+    n_mamba: int
+    attn_after: tuple[int, ...]
+    shared: bool
+
+    def attn_index(self) -> np.ndarray:
+        """Per Mamba2 layer, the attention application that follows it, or
+        -1."""
+        out = np.full((self.n_mamba,), -1, np.int32)
+        out[list(self.attn_after)] = np.arange(len(self.attn_after))
+        return out
+
+
 @dataclasses.dataclass
 class Model:
     cfg: ModelConfig
@@ -172,20 +195,29 @@ class Model:
     @property
     def n_super(self) -> int:
         c = self.cfg
-        if c.window > 0:
-            return c.n_layers // c.global_every
-        if c.kind == "hybrid" and c.shared_attn_every:
-            return c.n_layers // c.shared_attn_every
-        return 0
+        return c.n_layers // c.global_every if c.window > 0 else 0
 
     @property
     def n_tail(self) -> int:
         c = self.cfg
-        if c.window > 0:
-            return c.n_layers % c.global_every
-        if c.kind == "hybrid" and c.shared_attn_every:
-            return c.n_layers % c.shared_attn_every
-        return 0
+        return c.n_layers % c.global_every if c.window > 0 else 0
+
+    @property
+    def hybrid(self) -> HybridLayout:
+        c = self.cfg
+        if c.shared_attn_every:
+            per = c.shared_attn_every
+            return HybridLayout(c.n_layers, tuple(
+                range(per - 1, c.n_layers - c.n_layers % per, per)),
+                shared=True)
+        kinds = c.layer_types()
+        if not c.attn_every or kinds[0] == "attention":
+            raise ValueError(f"{c.name}: a hybrid needs shared_attn_every, "
+                             f"or attn_every with a Mamba2 layer first "
+                             f"(attn_offset >= 1)")
+        after = [kinds[:i].count("mamba") - 1
+                 for i, k in enumerate(kinds) if k == "attention"]
+        return HybridLayout(kinds.count("mamba"), tuple(after), shared=False)
 
     # ---------------------------------------------------------------- init
     def init(self, seed: int = 0) -> Params:
@@ -242,18 +274,16 @@ class Model:
             _mamba_leaves(mm, c, (L,), with_mlp=False)
             top["layers"], top_axes["layers"] = mm.leaves, mm.axes
         elif c.kind == "hybrid":
-            ns, nt, per = self.n_super, self.n_tail, c.shared_attn_every
+            hy = self.hybrid
             mm = _Maker(m.rng, dtype)
-            _mamba_leaves(mm, c, (ns, per), with_mlp=False)
+            _mamba_leaves(mm, c, (hy.n_mamba,), with_mlp=not hy.shared)
             top["mamba"], top_axes["mamba"] = mm.leaves, mm.axes
-            if nt:
-                mm = _Maker(mm.rng, dtype)
-                _mamba_leaves(mm, c, (nt,), with_mlp=False)
-                top["tail"], top_axes["tail"] = mm.leaves, mm.axes
             mm = _Maker(mm.rng, dtype)
-            _attn_leaves(mm, c, ())
-            _mlp_leaves(mm, c, ())
-            top["shared_attn"], top_axes["shared_attn"] = mm.leaves, mm.axes
+            stack = () if hy.shared else (len(hy.attn_after),)
+            _attn_leaves(mm, c, stack)
+            _mlp_leaves(mm, c, stack)
+            key = "shared_attn" if hy.shared else "attn"
+            top[key], top_axes[key] = mm.leaves, mm.axes
         elif c.kind in ("encdec", "audio"):
             mm = _Maker(m.rng, dtype)
             _attn_leaves(mm, c, (c.n_enc_layers,))
@@ -271,6 +301,29 @@ class Model:
         return top, top_axes
 
     # ------------------------------------------------------ shared pieces
+    @property
+    def _attn_scale(self):
+        return self.cfg.attention_multiplier or None
+
+    def _residual(self, x, y):
+        """``x + y``, the branch ``y`` scaled by the residual multiplier."""
+        m = self.cfg.residual_multiplier
+        return x + (y if m == 1.0 else y * m)
+
+    def _embed(self, params, tokens):
+        c = self.cfg
+        x = embedloss.embed_in(params["embed"], tokens, _dt(c.compute_dtype))
+        m = c.embedding_multiplier
+        return x if m == 1.0 else x * m
+
+    def _head_in(self, params, x):
+        """The final norm, with the logit scale folded in: the tied head
+        then gives the published logits (``logits_scaling`` divides
+        them)."""
+        c = self.cfg
+        x = rms_norm(x, params["ln_final"], c.norm_eps)
+        return x if c.logits_scaling == 1.0 else x * (1.0 / c.logits_scaling)
+
     def _attn_train(self, p, x, sin, cos, window, prefix=""):
         c = self.cfg
         b, s, d = x.shape
@@ -278,11 +331,13 @@ class Model:
         q = (h @ p[prefix + "wq"]).reshape(b, s, c.n_heads, c.hd)
         k = (h @ p[prefix + "wk"]).reshape(b, s, c.n_kv_heads, c.hd)
         v = (h @ p[prefix + "wv"]).reshape(b, s, c.n_kv_heads, c.hd)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
-        o = context_attention(q, k, v, causal=True, window=window)
+        if c.rope:
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
+        o = context_attention(q, k, v, causal=True, window=window,
+                              scale=self._attn_scale)
         o = o.reshape(b, s, -1) @ p[prefix + "wo"]
-        return x + shard(o, "batch", "seq", None), (k, v)
+        return self._residual(x, shard(o, "batch", "seq", None)), (k, v)
 
     def _attn_nocausal(self, p, x, prefix="", kv_from=None):
         """Encoder self-attention / decoder cross-attention (no RoPE)."""
@@ -293,9 +348,10 @@ class Model:
         q = (h @ p[prefix + "wq"]).reshape(b, s, c.n_heads, c.hd)
         k = (src @ p[prefix + "wk"]).reshape(b, src.shape[1], c.n_kv_heads, c.hd)
         v = (src @ p[prefix + "wv"]).reshape(b, src.shape[1], c.n_kv_heads, c.hd)
-        o = context_attention(q, k, v, causal=False, window=0)
+        o = context_attention(q, k, v, causal=False, window=0,
+                              scale=self._attn_scale)
         o = o.reshape(b, s, -1) @ p[prefix + "wo"]
-        return x + shard(o, "batch", "seq", None), (k, v)
+        return self._residual(x, shard(o, "batch", "seq", None)), (k, v)
 
     def _ffn(self, p, x):
         c = self.cfg
@@ -308,7 +364,7 @@ class Model:
                 y = y + self._dense_mlp(p, h)
         else:
             y = self._dense_mlp(p, h)
-        return x + shard(y, "batch", "seq", None)
+        return self._residual(x, shard(y, "batch", "seq", None))
 
     def _dense_mlp(self, p, h):
         hh = jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])
@@ -330,7 +386,7 @@ class Model:
         if c.kind in ("encdec", "audio"):
             return self._forward_encdec(params, batch, collect)
         tokens = batch["tokens"]
-        x = embedloss.embed_in(params["embed"], tokens, cdt)
+        x = self._embed(params, tokens)
         if c.kind == "vlm" and "patches" in batch:
             patches = batch["patches"].astype(cdt)
             x = jnp.concatenate([patches, x[:, patches.shape[1]:]], axis=1)
@@ -360,7 +416,7 @@ class Model:
             x, ys = _scan(self._maybe_remat(body), x, params["layers"])
             if collect:
                 col["k"], col["v"] = ys
-        out = rms_norm(x, params["ln_final"], c.norm_eps)
+        out = self._head_in(params, x)
         return (out, col) if collect else out
 
     def _forward_windowed(self, params, x, sin, cos, collect=False):
@@ -392,32 +448,37 @@ class Model:
         return x, col
 
     def _forward_hybrid(self, params, x, sin, cos, collect=False):
+        """The Mamba2 stack in runs, each run one scan, with the attention
+        applications between them."""
         c = self.cfg
+        hy = self.hybrid
 
         def mamba_body(xx, p):
             h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
             y, st = mamba_block(p, h, c.ssm)
-            xx = xx + shard(y, "batch", "seq", None)
+            xx = self._residual(xx, shard(y, "batch", "seq", None))
+            if not hy.shared:
+                xx = self._ffn(p, xx)
             return xx, st if collect else None
 
-        shared = params["shared_attn"]
-
-        def super_body(xx, p):
-            xx, sts = _scan(self._maybe_remat(mamba_body), xx, p)
-            xx, kv = self._attn_train(shared, xx, sin, cos, window=0)
-            xx = self._ffn(shared, xx)
-            return xx, (sts, kv) if collect else None
-
+        ends = hy.attn_after + (hy.n_mamba - 1,)
+        states, kvs = [], []
+        for k, (lo, hi) in enumerate(zip((-1,) + hy.attn_after, ends)):
+            if hi > lo:
+                run = jax.tree.map(lambda a: a[lo + 1:hi + 1], params["mamba"])
+                x, st = _scan(self._maybe_remat(mamba_body), x, run)
+                states.append(st)
+            if k < len(hy.attn_after):
+                a = params["shared_attn"] if hy.shared else jax.tree.map(
+                    lambda w: w[k], params["attn"])
+                x, kv = self._attn_train(a, x, sin, cos, window=0)
+                x = self._ffn(a, x)
+                kvs.append(kv)
         col: dict[str, Any] = {}
-        x, ys = _scan(self._maybe_remat(super_body), x, params["mamba"])
         if collect:
-            (col["conv"], col["state"]), (col["k_shared"],
-                                          col["v_shared"]) = ys
-        if self.n_tail:
-            x, ys = _scan(self._maybe_remat(mamba_body), x,
-                                 params["tail"])
-            if collect:
-                col["conv_tail"], col["state_tail"] = ys
+            col["conv"], col["state"] = (
+                jnp.concatenate(leaves) for leaves in zip(*states))
+            col["k"], col["v"] = (jnp.stack(leaves) for leaves in zip(*kvs))
         return x, col
 
     def _forward_encdec(self, params, batch, collect=False):
@@ -436,8 +497,7 @@ class Model:
         h = rms_norm(h, params["ln_enc_final"], c.norm_eps)
 
         tokens = batch["tokens"]
-        x = embedloss.embed_in(params["embed"], tokens, cdt)
-        x = shard(x, "batch", "seq", None)
+        x = shard(self._embed(params, tokens), "batch", "seq", None)
         s = x.shape[1]
         sin, cos = rope_table(jnp.arange(s), c.hd, c.rope_theta)
 
@@ -448,7 +508,7 @@ class Model:
             return xx, (kvs, kvc) if collect else None
 
         x, ys = _scan(self._maybe_remat(dec_body), x, params["dec"])
-        out = rms_norm(x, params["ln_final"], c.norm_eps)
+        out = self._head_in(params, x)
         if collect:
             col = {}
             (col["k_self"], col["v_self"]), (col["k_cross"],
@@ -527,18 +587,16 @@ class Model:
                 (c.n_layers, b, s.n_heads(c.d_model), s.head_dim, n),
                 jnp.float32)
         elif c.kind == "hybrid":
-            s = c.ssm
-            ns, nt, per = self.n_super, self.n_tail, c.shared_attn_every
+            # the Mamba2 layers' conv windows and float32 states beside the
+            # attention applications' KV stacks
+            s, hy = c.ssm, self.hybrid
             di, n = s.d_inner(c.d_model), s.d_state
-            cache["conv"] = make((ns, per, b, s.conv_width - 1, di + 2 * n))
+            cache["conv"] = make((hy.n_mamba, b, s.conv_width - 1, di + 2 * n))
             cache["state"] = make(
-                (ns, per, b, s.n_heads(c.d_model), s.head_dim, n), jnp.float32)
-            if nt:
-                cache["conv_tail"] = make((nt, b, s.conv_width - 1, di + 2 * n))
-                cache["state_tail"] = make(
-                    (nt, b, s.n_heads(c.d_model), s.head_dim, n), jnp.float32)
-            cache["k_shared"] = make(kvshape(ns, seq_len))
-            cache["v_shared"] = make(kvshape(ns, seq_len))
+                (hy.n_mamba, b, s.n_heads(c.d_model), s.head_dim, n),
+                jnp.float32)
+            cache["k"] = make(kvshape(len(hy.attn_after), seq_len))
+            cache["v"] = make(kvshape(len(hy.attn_after), seq_len))
         elif c.kind in ("encdec", "audio"):
             cache["k_self"] = make(kvshape(c.n_layers, seq_len))
             cache["v_self"] = make(kvshape(c.n_layers, seq_len))
@@ -568,17 +626,12 @@ class Model:
             if self.n_tail:
                 ax["k_tail"] = kv
                 ax["v_tail"] = kv
-        elif c.kind == "ssm":
+        elif c.kind in ("ssm", "hybrid"):
             ax["conv"] = (None, "batch", None, "ff")
             ax["state"] = (None, "batch", "q_heads", None, None)
-        elif c.kind == "hybrid":
-            ax["conv"] = (None, None, "batch", None, "ff")
-            ax["state"] = (None, None, "batch", "q_heads", None, None)
-            if self.n_tail:
-                ax["conv_tail"] = (None, "batch", None, "ff")
-                ax["state_tail"] = (None, "batch", "q_heads", None, None)
-            ax["k_shared"] = kv
-            ax["v_shared"] = kv
+            if c.kind == "hybrid":
+                ax["k"] = kv
+                ax["v"] = kv
         elif c.kind in ("encdec", "audio"):
             ax["k_self"] = kv
             ax["v_self"] = kv
@@ -633,9 +686,11 @@ class Model:
                 # own position, so mid-run admissions decode exactly as if
                 # solo
                 pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
-                sin, cos = rope_table(pos_b[:, None], c.hd, c.rope_theta)
-                q = apply_rope(q, sin, cos)
-                k = apply_rope(k, sin, cos).reshape(b, 1, -1)
+                if c.rope:
+                    sin, cos = rope_table(pos_b[:, None], c.hd, c.rope_theta)
+                    q = apply_rope(q, sin, cos)
+                    k = apply_rope(k, sin, cos)
+                k = k.reshape(b, 1, -1)
         if not cross:
             with jax.named_scope("kv_write"):
                 if rolling:
@@ -650,9 +705,9 @@ class Model:
             att_pos = jnp.int32(s_len - 1)  # attend to all enc kv
         with jax.named_scope("attn"):
             o = decode_attention(q[:, 0], k_cache[at], v_cache[at],
-                                 pos=att_pos)
+                                 pos=att_pos, scale=self._attn_scale)
             o = o.reshape(b, 1, -1) @ p[prefix + "wo"]
-            return x + o, (k_cache, v_cache)
+            return self._residual(x, o), (k_cache, v_cache)
 
     def decode_step(self, params: Params, cache, tokens: jax.Array):
         """tokens (B,) int32 -> (next_tokens (B,), cache').
@@ -660,15 +715,14 @@ class Model:
         Named scopes mark the planner's tasks in the HLO metadata, where a
         profiler shows each op's scope path; they leave the compiled code
         as it is: ``embed``; ``layer`` (a scan body) holding ``attn``,
-        ``kv_write`` (the cache writes) and ``ffn``, or ``ssm``; ``head``
-        (final norm and greedy pick)."""
+        ``kv_write`` (the cache writes) and ``ffn``, or ``ssm`` (then
+        ``ffn`` where the layer has its own MLP); ``head`` (final norm and
+        greedy pick)."""
         c = self.cfg
-        cdt = _dt(c.compute_dtype)
-        b = tokens.shape[0]
         pos = cache["pos"]
         with jax.named_scope("embed"):
-            x = embedloss.embed_in(params["embed"], tokens[:, None], cdt)
-            x = shard(x, "batch", None, None)
+            x = shard(self._embed(params, tokens[:, None]), "batch", None,
+                      None)
         newc = dict(cache)
 
         if c.kind in ("dense", "moe", "vlm") and c.window <= 0:
@@ -714,7 +768,7 @@ class Model:
                 (params["dec"], cache["k_cross"], cache["v_cross"],
                  jnp.arange(c.n_layers)))
         with jax.named_scope("head"):
-            x = rms_norm(x, params["ln_final"], c.norm_eps)
+            x = self._head_in(params, x)
             nxt = embedloss.greedy(x[:, 0], params["embed"],
                                    valid_vocab=self.cfg.vocab)
         newc["pos"] = pos + 1
@@ -756,37 +810,44 @@ class Model:
         return x
 
     def _decode_hybrid(self, params, x, cache, newc, pos):
+        """One token through a hybrid: one scan over the Mamba2 stack,
+        whose conv windows and states go through it as per-layer slices,
+        as the ``ssm`` stack's do; after a Mamba2 layer that an attention
+        application follows, a ``lax.cond`` runs it. The KV stacks ride the
+        scan's carry and take the new token in place at the application's
+        index (see :meth:`_attn_decode`)."""
         c = self.cfg
-        shared = params["shared_attn"]
+        hy = self.hybrid
 
-        def mamba_body(xx, xs):
-            p, conv, st = xs
-            with jax.named_scope("layer"), jax.named_scope("ssm"):
-                h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
-                y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
-                                            ssd_state=st)
-                xx = xx + y
-            return xx, (conv, st)
-
-        def super_body(carry, xs):
-            xx, kv = carry
-            p, conv, st, i = xs
-            xx, (conv, st) = _scan(mamba_body, xx, (p, conv, st))
+        def attend(xx, kv, k):
+            p = params["shared_attn"] if hy.shared else jax.tree.map(
+                lambda w: w[k], params["attn"])
             with jax.named_scope("layer"):
-                xx, kv = self._attn_decode(shared, xx, kv, pos, layer=i)
+                xx, kv = self._attn_decode(p, xx, kv, pos, layer=k)
                 with jax.named_scope("ffn"):
-                    xx = self._ffn(shared, xx)
+                    xx = self._ffn(p, xx)
+            return xx, kv
+
+        def body(carry, xs):
+            xx, kv = carry
+            p, conv, st, k = xs
+            with jax.named_scope("layer"):
+                with jax.named_scope("ssm"):
+                    h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
+                    y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
+                                                ssd_state=st)
+                    xx = self._residual(xx, y)
+                if not hy.shared:
+                    with jax.named_scope("ffn"):
+                        xx = self._ffn(p, xx)
+            xx, kv = jax.lax.cond(k >= 0, attend, lambda xx, kv, k: (xx, kv),
+                                  xx, kv, k)
             return (xx, kv), (conv, st)
 
-        (x, (newc["k_shared"], newc["v_shared"])), (
-            newc["conv"], newc["state"]) = _scan(
-            super_body, (x, (cache["k_shared"], cache["v_shared"])),
+        (x, (newc["k"], newc["v"])), (newc["conv"], newc["state"]) = _scan(
+            body, (x, (cache["k"], cache["v"])),
             (params["mamba"], cache["conv"], cache["state"],
-             jnp.arange(self.n_super)))
-        if self.n_tail:
-            x, (newc["conv_tail"], newc["state_tail"]) = _scan(
-                mamba_body, x, (params["tail"], cache["conv_tail"],
-                                cache["state_tail"]))
+             jnp.asarray(hy.attn_index())))
         return x
 
     # -------------------------------------------------------------- prefill
@@ -818,7 +879,7 @@ class Model:
                 dst.dtype)
 
         for key, src in col.items():
-            if key in ("conv", "state", "conv_tail", "state_tail"):
+            if key.startswith(("conv", "state")):
                 cache[key] = src.astype(cache[key].dtype)
                 continue
             # the forward's (..., B, S, Hkv, hd), heads merged

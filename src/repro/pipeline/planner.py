@@ -72,16 +72,21 @@ class BlockCost:
         return max(self.flops / dev.peak_flops, self.bytes_moved / dev.hbm_bw)
 
 
-def _layer_cost(cfg: ModelConfig, tokens: int, mode: str) -> tuple[float, float]:
-    """(flops, bytes) of one decoder block for `tokens` tokens per step."""
+def _layer_cost(cfg: ModelConfig, tokens: int, mode: str,
+                mixer: str) -> tuple[float, float]:
+    """(flops, bytes) of one decoder block for `tokens` tokens per step;
+    ``mixer`` is the block's entry of ``cfg.layer_types()``."""
     d = cfg.d_model
     hq, hkv, hd = max(cfg.n_heads, 1), max(cfg.n_kv_heads, 1), cfg.hd
-    if cfg.kind == "ssm" or (cfg.kind == "hybrid"):
+    if mixer == "mamba":
         s = cfg.ssm
         di, n = s.d_inner(d), s.d_state
         flops = 2 * tokens * d * (2 * di + 2 * n + s.n_heads(d)) \
             + 2 * tokens * di * n * 2 + 2 * tokens * di * d
         params = d * (2 * di + 2 * n + s.n_heads(d)) + di * d
+        if cfg.attn_every:  # granite form: each Mamba2 layer has an MLP
+            flops += 2 * tokens * 3 * d * cfg.d_ff
+            params += 3 * d * cfg.d_ff
     else:
         attn_p = d * hq * hd + 2 * d * hkv * hd + hq * hd * d
         ff = cfg.moe.d_ff_expert * cfg.moe.top_k * 3 * d if cfg.moe \
@@ -94,8 +99,9 @@ def _layer_cost(cfg: ModelConfig, tokens: int, mode: str) -> tuple[float, float]
                            if cfg.moe else 3 * d * cfg.d_ff)
     byte_per = 2
     bytes_moved = params * byte_per + tokens * d * byte_per * 4
-    if mode == "decode" and cfg.kind not in ("ssm",):
-        # decode reads the KV cache for the active tokens' streams
+    if mode == "decode" and (mixer == "attention" or cfg.shared_attn_every):
+        # decode reads the KV cache for the active tokens' streams (a
+        # zamba2-form shared attention block is priced into every layer)
         bytes_moved += tokens * 2 * hkv * hd * byte_per * 512  # ~cache slice
     return float(flops), float(bytes_moved)
 
@@ -109,9 +115,10 @@ def model_chain(cfg: ModelConfig, *, tokens_per_step: int, mode: str,
     emb_bytes = tokens_per_step * d * 2 + cfg.padded_vocab * d * 2 / 64
     blocks.append(BlockCost("ingest", 1e6, 1e6, replicable=False))
     blocks.append(BlockCost("embed", emb_flops, emb_bytes))
-    lf, lb = _layer_cost(cfg, tokens_per_step, mode)
-    for i in range(cfg.n_layers):
-        blocks.append(BlockCost(f"layer{i}", lf, lb))
+    costs = {m: _layer_cost(cfg, tokens_per_step, mode, m)
+             for m in set(cfg.layer_types())}
+    for i, mixer in enumerate(cfg.layer_types()):
+        blocks.append(BlockCost(f"layer{i}", *costs[mixer]))
     head_flops = 2 * tokens_per_step * d * cfg.padded_vocab
     head_bytes = cfg.padded_vocab * d * 2
     blocks.append(BlockCost("head", head_flops, head_bytes))

@@ -148,3 +148,33 @@ def test_stablelm_served_step_updates_cache_in_place(one_chip):
         for key in ("k", "v"):
             moves = _cache_moves(hlo, cache[key])
             assert not moves, f"{name} moves cache {key!r}: {moves}"
+
+
+def test_granite_served_step_keeps_kv_in_place(one_chip):
+    """granite-4.0-h-micro's step and lane reset as the engine jits them,
+    at 32 slots x 1024 positions: the step fits one chip; its two KV
+    stacks stay one buffer each that the attention applications write a
+    token into, neither copied nor relaid out; what it needs besides its
+    arguments is the Mamba2 states' one pass through the layer scan (as
+    mamba2-1.3b's step has it) and a few activations."""
+    model = Model(get_config("granite-4.0-h-micro"))
+    place = lambda s: _spec(s.shape, s.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(place, model.abstract_params())
+    cache = jax.tree.map(place, model.init_cache(32, 1024, abstract=True))
+    step = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, _spec((32,), jnp.int32, one_chip)).compile()
+    reset_lane = jax.jit(model.reset_cache_lane, donate_argnums=(0,)).lower(
+        cache, _spec((), jnp.int32, one_chip)).compile()
+    mem = step.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < DECODE_STEP_BUDGET_BYTES
+    state = cache["state"]
+    state_bytes = state.size * jnp.dtype(state.dtype).itemsize
+    assert mem.temp_size_in_bytes < state_bytes + SERVED_STEP_TEMP_BYTES, (
+        f"served step temporaries {mem.temp_size_in_bytes} B")
+    assert step.output_formats[1] == step.input_formats[0][1]
+    for name, program in (("step", step), ("reset_cache_lane", reset_lane)):
+        hlo = program.as_text()
+        for key in ("k", "v"):
+            moves = _cache_moves(hlo, cache[key])
+            assert not moves, f"{name} moves cache {key!r}: {moves}"

@@ -65,8 +65,8 @@ def test_smoke_decode_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["stablelm-3b", "gemma3-1b", "mamba2-1.3b",
-                                  "zamba2-7b", "whisper-small",
-                                  "arctic-480b"])
+                                  "zamba2-7b", "granite-4.0-h-micro",
+                                  "whisper-small", "arctic-480b"])
 def test_decode_matches_forward(arch):
     """Streaming tokens through decode_step must reproduce the greedy token
     the full forward pass would pick at every position (exact cache check).

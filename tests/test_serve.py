@@ -70,7 +70,7 @@ def test_engine_batches_capacity():
 
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["stablelm-3b", "gemma3-1b", "mamba2-1.3b",
-                                  "zamba2-7b"])
+                                  "zamba2-7b", "granite-4.0-h-micro"])
 @pytest.mark.parametrize("offset", [1, 3, 6])
 def test_mid_run_admission_byte_identical(arch, offset):
     """A request admitted while another is mid-decode must produce exactly
