@@ -709,14 +709,35 @@ class Model:
             o = o.reshape(b, 1, -1) @ p[prefix + "wo"]
             return self._residual(x, o), (k_cache, v_cache)
 
+    def _ssm_decode(self, p, x, conv_state, layer):
+        """Mamba2 of one decode token: x (B, 1, D); conv_state = (conv,
+        state), the whole stacks (L, B, W-1, C) and float32 (L, B, H, P, N)
+        carried through the layer scan, read at ``[layer]`` and written
+        back there in place: stacks the scan took in as per-layer slices
+        and stacked back out would be copied whole every step. Returns
+        (x', (conv', state'))."""
+        c = self.cfg
+        conv, state = conv_state
+        with jax.named_scope("ssm"):
+            h = rms_norm(x, p["ln_ssm"], c.norm_eps)
+            y, (cv, st) = mamba_block(p, h, c.ssm, conv_cache=conv[layer],
+                                      ssd_state=state[layer])
+            x = self._residual(x, y)
+        with jax.named_scope("state_write"):
+            conv = jax.lax.dynamic_update_index_in_dim(
+                conv, cv.astype(conv.dtype), layer, 0)
+            state = jax.lax.dynamic_update_index_in_dim(state, st, layer, 0)
+        return x, (conv, state)
+
     def decode_step(self, params: Params, cache, tokens: jax.Array):
         """tokens (B,) int32 -> (next_tokens (B,), cache').
 
         Named scopes mark the planner's tasks in the HLO metadata, where a
         profiler shows each op's scope path; they leave the compiled code
         as it is: ``embed``; ``layer`` (a scan body) holding ``attn``,
-        ``kv_write`` (the cache writes) and ``ffn``, or ``ssm`` (then
-        ``ffn`` where the layer has its own MLP); ``head`` (final norm and
+        ``kv_write`` (the cache writes) and ``ffn``, or ``ssm`` and
+        ``state_write`` (the conv window and state writes; then ``ffn``
+        where the layer has its own MLP); ``head`` (final norm and
         greedy pick)."""
         c = self.cfg
         pos = cache["pos"]
@@ -740,16 +761,15 @@ class Model:
         elif c.window > 0:
             x = self._decode_windowed(params, x, cache, newc, pos)
         elif c.kind == "ssm":
-            def body(xx, xs):
-                p, conv, st = xs
-                with jax.named_scope("layer"), jax.named_scope("ssm"):
-                    h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
-                    y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
-                                                ssd_state=st)
-                    xx = xx + y
-                return xx, (conv, st)
-            x, (newc["conv"], newc["state"]) = _scan(
-                body, x, (params["layers"], cache["conv"], cache["state"]))
+            def body(carry, xs):
+                xx, cs = carry
+                p, i = xs
+                with jax.named_scope("layer"):
+                    xx, cs = self._ssm_decode(p, xx, cs, i)
+                return (xx, cs), None
+            (x, (newc["conv"], newc["state"])), _ = _scan(
+                body, (x, (cache["conv"], cache["state"])),
+                (params["layers"], jnp.arange(c.n_layers)))
         elif c.kind == "hybrid":
             x = self._decode_hybrid(params, x, cache, newc, pos)
         elif c.kind in ("encdec", "audio"):
@@ -810,13 +830,12 @@ class Model:
         return x
 
     def _decode_hybrid(self, params, x, cache, newc, pos):
-        """One token through a hybrid: one scan over the Mamba2 stack,
-        whose conv windows and states go through it as per-layer slices,
-        as the ``ssm`` stack's do; after a Mamba2 layer that an attention
-        application follows, a ``lax.cond`` runs it. The KV stacks ride the
-        scan's carry and take the new token in place at the application's
-        index (see :meth:`_attn_decode`)."""
-        c = self.cfg
+        """One token through a hybrid: one scan over the Mamba2 stack, whose
+        conv windows and states ride the scan's carry and are updated in
+        place at the layer's index (see :meth:`_ssm_decode`); after a
+        Mamba2 layer that an attention application follows, a ``lax.cond``
+        runs it. The KV stacks ride the carry too and take the new token in
+        place at the application's index (see :meth:`_attn_decode`)."""
         hy = self.hybrid
 
         def attend(xx, kv, k):
@@ -829,25 +848,21 @@ class Model:
             return xx, kv
 
         def body(carry, xs):
-            xx, kv = carry
-            p, conv, st, k = xs
+            xx, kv, cs = carry
+            p, i, k = xs
             with jax.named_scope("layer"):
-                with jax.named_scope("ssm"):
-                    h = rms_norm(xx, p["ln_ssm"], c.norm_eps)
-                    y, (conv, st) = mamba_block(p, h, c.ssm, conv_cache=conv,
-                                                ssd_state=st)
-                    xx = self._residual(xx, y)
+                xx, cs = self._ssm_decode(p, xx, cs, i)
                 if not hy.shared:
                     with jax.named_scope("ffn"):
                         xx = self._ffn(p, xx)
             xx, kv = jax.lax.cond(k >= 0, attend, lambda xx, kv, k: (xx, kv),
                                   xx, kv, k)
-            return (xx, kv), (conv, st)
+            return (xx, kv, cs), None
 
-        (x, (newc["k"], newc["v"])), (newc["conv"], newc["state"]) = _scan(
-            body, (x, (cache["k"], cache["v"])),
-            (params["mamba"], cache["conv"], cache["state"],
-             jnp.asarray(hy.attn_index())))
+        init = (x, (cache["k"], cache["v"]), (cache["conv"], cache["state"]))
+        (x, (newc["k"], newc["v"]), (newc["conv"], newc["state"])), _ = _scan(
+            body, init, (params["mamba"], jnp.arange(hy.n_mamba),
+                         jnp.asarray(hy.attn_index())))
         return x
 
     # -------------------------------------------------------------- prefill

@@ -109,36 +109,47 @@ def test_stablelm_decode_step_fits_one_chip(one_chip):
         f"temps {mem.temp_size_in_bytes / 1e9:.2f} GB)")
 
 
-def _cache_moves(hlo: str, leaf) -> list[str]:
+def _cache_moves(hlo: str, leaf, *, layer_writes: bool = False) -> list[str]:
     """HLO instructions, fused ones included, that move a whole cache leaf
     or one layer of it: a ``copy`` of that shape, or a
     ``dynamic-update-slice`` whose update has it. A one-layer shape is
     counted with and without its layer axis. A dynamic-update-slice that
-    writes a token or a lane into the cache in place is not a move."""
+    writes a token or a lane into the cache in place is not a move; with
+    ``layer_writes`` neither is one that writes one layer in place (an SSM
+    state, which every lane changes each step)."""
     dims = [leaf.shape, (1,) + leaf.shape[1:], leaf.shape[1:]]
-    dt = jnp.dtype(leaf.dtype).name.replace("bfloat16", "bf16")
+    dt = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(leaf.dtype).name]
     moved = {f"{dt}[{','.join(map(str, d))}]" for d in dims}
+    written = {f"{dt}[{','.join(map(str, leaf.shape))}]"} if layer_writes \
+        else moved
     inst = re.compile(r"%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(%([^,)\s]+)"
                       r"(?:, %([^,)\s]+))?")
     found = [m for m in map(inst.search, hlo.splitlines()) if m]
     shape = {m[1]: m[2] for m in found}
     return [f"{m[3]} {m[1]}" for m in found
             if m[3] == "copy" and m[2] in moved
-            or m[3] == "dynamic-update-slice" and shape.get(m[5]) in moved]
+            or m[3] == "dynamic-update-slice" and shape.get(m[5]) in written]
+
+
+def _served_step(arch, slots, sharding):
+    """(cache, step, lane reset) for ``arch`` at full width, ``slots`` x
+    1024 positions, compiled as the engine jits them: cache donated."""
+    model = Model(get_config(arch))
+    place = lambda s: _spec(s.shape, s.dtype, sharding)  # noqa: E731
+    params = jax.tree.map(place, model.abstract_params())
+    cache = jax.tree.map(place, model.init_cache(slots, 1024, abstract=True))
+    step = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, _spec((slots,), jnp.int32, sharding)).compile()
+    reset_lane = jax.jit(model.reset_cache_lane, donate_argnums=(0,)).lower(
+        cache, _spec((), jnp.int32, sharding)).compile()
+    return cache, step, reset_lane
 
 
 def test_stablelm_served_step_updates_cache_in_place(one_chip):
     """The step and the lane reset as the engine jits them, with the cache
     in the device's default layout: the cache stays one buffer that each
     layer writes a token into, neither copied nor relaid out."""
-    model = Model(get_config("stablelm-3b"))
-    place = lambda s: _spec(s.shape, s.dtype, one_chip)  # noqa: E731
-    params = jax.tree.map(place, model.abstract_params())
-    cache = jax.tree.map(place, model.init_cache(8, 1024, abstract=True))
-    step = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
-        params, cache, _spec((8,), jnp.int32, one_chip)).compile()
-    reset_lane = jax.jit(model.reset_cache_lane, donate_argnums=(0,)).lower(
-        cache, _spec((), jnp.int32, one_chip)).compile()
+    cache, step, reset_lane = _served_step("stablelm-3b", 8, one_chip)
     mem = step.memory_analysis()
     assert mem.temp_size_in_bytes < SERVED_STEP_TEMP_BYTES, (
         f"served step temporaries {mem.temp_size_in_bytes} B")
@@ -150,29 +161,44 @@ def test_stablelm_served_step_updates_cache_in_place(one_chip):
             assert not moves, f"{name} moves cache {key!r}: {moves}"
 
 
-def test_granite_served_step_keeps_kv_in_place(one_chip):
-    """granite-4.0-h-micro's step and lane reset as the engine jits them,
-    at 32 slots x 1024 positions: the step fits one chip; its two KV
-    stacks stay one buffer each that the attention applications write a
-    token into, neither copied nor relaid out; what it needs besides its
-    arguments is the Mamba2 states' one pass through the layer scan (as
-    mamba2-1.3b's step has it) and a few activations."""
-    model = Model(get_config("granite-4.0-h-micro"))
-    place = lambda s: _spec(s.shape, s.dtype, one_chip)  # noqa: E731
-    params = jax.tree.map(place, model.abstract_params())
-    cache = jax.tree.map(place, model.init_cache(32, 1024, abstract=True))
-    step = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
-        params, cache, _spec((32,), jnp.int32, one_chip)).compile()
-    reset_lane = jax.jit(model.reset_cache_lane, donate_argnums=(0,)).lower(
-        cache, _spec((), jnp.int32, one_chip)).compile()
+def _assert_state_in_place(cache, step, reset_lane):
+    """The conv windows and float32 states stay one buffer each that every
+    layer updates at its own index: neither copied whole nor a layer
+    copied out; what the step needs besides its arguments is under one
+    layer's state and a few activations."""
     mem = step.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < DECODE_STEP_BUDGET_BYTES
     state = cache["state"]
-    state_bytes = state.size * jnp.dtype(state.dtype).itemsize
-    assert mem.temp_size_in_bytes < state_bytes + SERVED_STEP_TEMP_BYTES, (
+    layer_bytes = state.size // state.shape[0] * state.dtype.itemsize
+    assert mem.temp_size_in_bytes < layer_bytes + SERVED_STEP_TEMP_BYTES, (
         f"served step temporaries {mem.temp_size_in_bytes} B")
     assert step.output_formats[1] == step.input_formats[0][1]
+    for name, program in (("step", step), ("reset_cache_lane", reset_lane)):
+        hlo = program.as_text()
+        for key in ("conv", "state"):
+            moves = _cache_moves(hlo, cache[key], layer_writes=True)
+            assert not moves, f"{name} moves cache {key!r}: {moves}"
+
+
+def test_mamba2_served_step_updates_state_in_place(one_chip):
+    """mamba2-1.3b's step and lane reset as the engine jits them, at 32
+    slots: the 3.2 GB of float32 states are read and written in place, a
+    layer at a time, with no second copy of them."""
+    _assert_state_in_place(*_served_step("mamba2-1.3b", 32, one_chip))
+
+
+def test_granite_served_step_keeps_kv_in_place(one_chip):
+    """granite-4.0-h-micro's step and lane reset as the engine jits them,
+    at 32 slots x 1024 positions: the step fits one chip; its two KV
+    stacks stay one buffer each that the attention applications write a
+    token into, neither copied nor relaid out; its Mamba2 conv windows and
+    float32 states are updated in place, a layer at a time, so what it
+    needs besides its arguments is under one layer's state and a few
+    activations."""
+    cache, step, reset_lane = _served_step("granite-4.0-h-micro", 32,
+                                           one_chip)
+    _assert_state_in_place(cache, step, reset_lane)
     for name, program in (("step", step), ("reset_cache_lane", reset_lane)):
         hlo = program.as_text()
         for key in ("k", "v"):

@@ -109,7 +109,7 @@ def _scope_paths(model, cache_len=32):
 @pytest.mark.parametrize("arch,want,absent", [
     ("stablelm-3b", ["embed", "layer/attn", "layer/kv_write", "layer/ffn",
                      "head"], ["layer/ssm"]),
-    ("mamba2-1.3b", ["embed", "layer/ssm", "head"],
+    ("mamba2-1.3b", ["embed", "layer/ssm", "layer/state_write", "head"],
      ["layer/attn", "layer/kv_write"]),
 ])
 def test_decode_step_carries_task_scopes(arch, want, absent):
