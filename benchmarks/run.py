@@ -7,8 +7,6 @@
              throughput, pipeline decomposition per strategy.
   fig3_fig4: strategy wall-clock times vs chain length and resources
              (paper Figs. 3-4).
-  roofline : three-term roofline per (arch x shape x mesh) from the dry-run
-             artifacts (assignment §Roofline) — see benchmarks/roofline.py.
 
 Prints ``name,...,us_per_call/derived`` CSV rows per the harness contract.
 Use --full for the paper-scale 1000-chain simulation.
@@ -23,7 +21,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
@@ -188,7 +185,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-scale simulation (1000 chains)")
     ap.add_argument("--only", default=None,
-                    choices=[None, "table1", "table2", "fig34", "roofline"])
+                    choices=[None, "table1", "table2", "fig34"])
     args = ap.parse_args()
     n = 1000 if args.full else 200
     if args.only in (None, "table2"):
@@ -197,12 +194,6 @@ def main() -> None:
         table1(n_chains=n)
     if args.only in (None, "fig34"):
         fig3_fig4()
-    if args.only in (None, "roofline"):
-        try:
-            from benchmarks.roofline import print_roofline
-            print_roofline()
-        except Exception as e:  # noqa: BLE001
-            print(f"# roofline: dry-run artifacts unavailable ({e})")
 
 
 if __name__ == "__main__":

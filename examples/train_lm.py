@@ -5,7 +5,7 @@ Presets:
          ~a minute on this CPU container and shows a clear loss drop.
   100m : ~100M-param model, a few hundred steps — the deliverable-scale run
          (hours on CPU; the intended substrate is a TPU slice where the same
-         program runs under the production mesh via repro.launch.train).
+         program runs through repro.launch.train).
 
 Includes async checkpointing and a mid-run restore to demonstrate
 fault-tolerant restart.

@@ -102,8 +102,7 @@ def twocatac_compute_solution(
     ``_memo``: optional (s, b, l) -> Solution memo table. The paper's 2CATAC
     is the un-memoized exponential recursion; passing a dict makes it a
     polynomial-size DP over reachable states with identical results (same
-    comparison order) — used as a beyond-paper optimization (see
-    EXPERIMENTS.md §Perf-algorithms).
+    comparison order) — used as a beyond-paper optimization.
     """
     if _memo is not None:
         key = (s, b, l)
@@ -241,7 +240,7 @@ def twocatac(
     core budgets; periods are in the chain's time unit (µs for the DVB-S2
     tables). ``memoize=False`` is the paper's exponential recursion;
     ``memoize=True`` is the result-identical DP variant (beyond-paper
-    speedup — see EXPERIMENTS.md §Perf-algorithms).
+    speedup).
     """
 
     def cs(c: TaskChain, s: int, bb: int, ll: int, p: float) -> Solution:

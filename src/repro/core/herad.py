@@ -14,7 +14,7 @@ Two result-equivalent implementations are provided:
   Algo. 10 CompareCells, Algo. 11 ExtractSolution).
 - ``herad``: numpy-vectorized over the (big, little) budget plane.
 
-Vectorization note (beyond-paper, see EXPERIMENTS.md §Perf-algorithms): the
+Vectorization note (beyond-paper): the
 CompareCells rule of Algo. 10 — "N on strictly smaller period; on ties, N if it
 exchanges big for little or uses fewer-or-equal of both" — is exactly the
 lexicographic order on (period, big-cores-used, little-cores-used):

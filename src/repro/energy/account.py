@@ -69,6 +69,17 @@ class StageEnergy:
         return self.busy + self.idle
 
 
+def _stage_order_sum(terms) -> float:
+    """Plain left-to-right float adds, the arithmetic the vectorized sweeps
+    of ``repro.energy.pareto`` replay per cell. Not the built-in ``sum()``:
+    since Python 3.12 it sums floats with compensation, so the two would
+    differ in the last bit."""
+    acc = 0.0
+    for t in terms:
+        acc += t
+    return acc
+
+
 @dataclasses.dataclass(frozen=True)
 class EnergyReport:
     """Per-frame energy of a schedule evaluated at ``period``."""
@@ -80,11 +91,11 @@ class EnergyReport:
 
     @property
     def busy(self) -> float:
-        return sum(s.busy for s in self.stages)
+        return _stage_order_sum(s.busy for s in self.stages)
 
     @property
     def idle(self) -> float:
-        return sum(s.idle for s in self.stages)
+        return _stage_order_sum(s.idle for s in self.stages)
 
     @property
     def total(self) -> float:

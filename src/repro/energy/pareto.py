@@ -64,7 +64,8 @@ Complexity (n tasks, budgets b/l, |F| frequency levels): one
 ``CandidateTable`` build is O(n^2 |F|) vectorized; a DP query is
 O(n^2 |F|) candidate plane-updates of O(b l) each; a budget sweep is
 O(n b l) vectorized steps per frequency profile. See docs/energy.md for
-the before/after table and BENCH_sched.json for measured latencies.
+the before/after table; ``benchmarks/sched_perf.py`` measures host
+latencies.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Production training driver.
 
-On real hardware this launches under the production mesh (use --mesh); on
-this CPU container it runs the same program on whatever devices exist.
+It runs the same program on whatever devices exist: one CPU device in a
+test, the chips of a TPU host.
 
   PYTHONPATH=src python -m repro.launch.train --arch stablelm-3b --smoke \
       --steps 50 --batch 8 --seq 64
@@ -19,7 +19,6 @@ from repro.data import Prefetcher, SyntheticLM
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import get_config, get_smoke_config
 from repro.models.transformer import Model
-from repro.sharding import use_ctx
 from repro.train import OptConfig, TrainConfig, make_train_step
 from repro.train.step import init_train_state
 

@@ -16,21 +16,20 @@ Distribution:
     query sequence is sharded over the 'model' mesh axis (shard_map), KV is
     gathered per layer; masks use absolute positions via the shard offset.
     This keeps attention TP-effective for *any* head count (no head
-    divisibility constraint — see DESIGN.md §4).
+    divisibility constraint).
   - ``decode_attention_sharded`` : flash-decoding — the KV cache is sharded
     along the sequence axis over 'model'; each shard computes a partial
     softmax and the results merge with the log-sum-exp trick via psum.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import current_ctx, scan_unroll, shard_map
+from repro.sharding import current_ctx, shard_map
 
 _NEG = -1e30
 
@@ -134,8 +133,7 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
     m0 = qg[..., 0] * 0 + _NEG
     l0 = qg[..., 0] * 0
     a0 = qg * 0
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (ks, vs, chunk_ids),
-                                  unroll=scan_unroll())
+    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (ks, vs, chunk_ids))
     o = acc / jnp.maximum(l, 1e-30)[..., None]
     return _ungroup(o).astype(q.dtype)
 

@@ -1,15 +1,13 @@
 """Model configuration dataclasses and the architecture registry.
 
-Every assigned architecture is a ``ModelConfig``; input shapes are
-``ShapeSpec``s. ``input_specs`` (in repro.launch.specs) turns (config, shape)
-into jax.ShapeDtypeStruct stand-ins for the dry-run.
+Every assigned architecture is a ``ModelConfig``, registered with a
+reduced smoke-size twin (``get_config`` / ``get_smoke_config``).
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Literal
 
-import numpy as np
 
 Kind = Literal["dense", "moe", "ssm", "hybrid", "encdec", "vlm", "audio"]
 
@@ -201,34 +199,6 @@ class ModelConfig:
         total += self.n_layers * per_moe
         active = embed + head + d + self.n_layers * per_moe_active
         return total, active
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    mode: Literal["train", "prefill", "decode"]
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
-}
-
-# Archs for which long_500k is skipped (pure full-attention; the assignment's
-# skip rule) — see DESIGN.md §5.
-LONG_CONTEXT_ARCHS = {"mamba2-1.3b", "zamba2-7b", "gemma3-1b", "gemma3-12b"}
-
-
-def shape_cells(arch: str) -> list[str]:
-    """The dry-run cells defined for an architecture."""
-    cells = ["train_4k", "prefill_32k", "decode_32k"]
-    if arch in LONG_CONTEXT_ARCHS:
-        cells.append("long_500k")
-    return cells
 
 
 _REGISTRY: dict[str, "ModelConfig"] = {}

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import current_ctx, scan_unroll, shard_map
+from repro.sharding import current_ctx, shard_map
 
 _NEG = -1e30
 
@@ -146,8 +146,7 @@ def _lm_loss_fwd_impl(x, table, labels, valid, seq_chunk, axis):
             mask = (lc >= 0).astype(jnp.float32)
             return None, (jnp.sum(nll * mask), jnp.sum(mask))
 
-        _, (nll_sum, cnt) = jax.lax.scan(chunk_nll, None, (xs, ls),
-                                         unroll=scan_unroll())
+        _, (nll_sum, cnt) = jax.lax.scan(chunk_nll, None, (xs, ls))
         tot, n = jnp.sum(nll_sum), jnp.sum(cnt)
         if batch_axes:  # global token mean across the data shards
             tot = jax.lax.psum(tot, batch_axes)
@@ -199,8 +198,7 @@ def _lm_loss_bwd_impl(valid, seq_chunk, axis, res, g):
             return gt_acc, gx_c
 
         gt0 = jnp.zeros_like(tbl, dtype=jnp.float32) + 0.0 * xs[0, :1, :1, 0].sum()
-        gt, gx_chunks = jax.lax.scan(chunk_bwd, gt0, (xs, ls),
-                                     unroll=scan_unroll())
+        gt, gx_chunks = jax.lax.scan(chunk_bwd, gt0, (xs, ls))
         bl = xs.shape[1]
         gx = gx_chunks.transpose(1, 0, 2, 3).reshape(bl, n_chunk * cs, d)
         if seq_sharded:  # vjp of all_gather = reduce-scatter onto seq
@@ -276,8 +274,7 @@ def _ce_chunked(x, table, labels, valid, seq_chunk):
         mask = (lc >= 0).astype(jnp.float32)
         return None, (jnp.sum((lse - gold) * mask), jnp.sum(mask))
 
-    _, (nll_sum, cnt) = jax.lax.scan(chunk_nll, None, (xs, ls),
-                                         unroll=scan_unroll())
+    _, (nll_sum, cnt) = jax.lax.scan(chunk_nll, None, (xs, ls))
     tot, n = jnp.sum(nll_sum), jnp.sum(cnt)
     if rem:
         xc, lc = x[:, n_chunk * cs:], labels[:, n_chunk * cs:]

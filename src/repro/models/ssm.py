@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.models.config import SSMConfig
 from repro.models.layers import rms_norm
-from repro.sharding import scan_unroll, shard
+from repro.sharding import shard
 
 
 def ssd_ref(x, dt, A, B, C, chunk: int = 128, init_state=None):
@@ -64,8 +64,7 @@ def ssd_ref(x, dt, A, B, C, chunk: int = 128, init_state=None):
         return s_new, s_prev
 
     states_in = jnp.moveaxis(chunk_state, 1, 0), jnp.moveaxis(tot, 1, 0)
-    final, prevs = jax.lax.scan(body, s0, states_in,
-                                unroll=scan_unroll())
+    final, prevs = jax.lax.scan(body, s0, states_in)
     prev_states = jnp.moveaxis(prevs, 0, 1)        # state entering each chunk
 
     # Inter-chunk contribution: y[i] += C_i . (e^{seg_i} S_prev)

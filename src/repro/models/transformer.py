@@ -19,15 +19,14 @@ Families (ModelConfig.kind):
                      cross-attention; frame embeddings from the frontend stub.
 
 Layer stacks are scanned (`lax.scan`) with per-layer remat, so the lowered
-HLO stays compact for the 512-device dry-runs. All activations follow the
+HLO holds one layer body however deep the model. All activations follow the
 context-parallel layout (batch over 'data'/'pod', sequence over 'model') in
-train/prefill, and the Megatron/flash-decoding layout in decode — see
-DESIGN.md §4.
+train/prefill, and the Megatron/flash-decoding layout in decode
+(`repro.sharding` maps the logical axes to the mesh).
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any
 
@@ -41,17 +40,9 @@ from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope, rms_norm, rope_table
 from repro.models.moe import moe_apply
 from repro.models.ssm import mamba_block
-from repro.sharding import scan_unroll, shard
+from repro.sharding import shard
 
 Params = Any
-
-
-def _scan(body, init, xs, **kw):
-    """lax.scan that honours the analysis-mode unroll flag (dryrun.py)."""
-    kw.setdefault("unroll", 1)
-    u = scan_unroll()
-    return jax.lax.scan(body, init, xs, unroll=True if u else kw["unroll"])
-
 
 
 def _dt(name: str):
@@ -401,7 +392,7 @@ class Model:
                 y, st = mamba_block(p, h, c.ssm)
                 return xx + shard(y, "batch", "seq", None), \
                     st if collect else None
-            x, ys = _scan(self._maybe_remat(body), x, params["layers"])
+            x, ys = jax.lax.scan(self._maybe_remat(body), x, params["layers"])
             if collect:
                 col["conv"], col["state"] = ys
         elif c.kind == "hybrid":
@@ -413,7 +404,7 @@ class Model:
                 xx, kv = self._attn_train(p, xx, sin, cos, window=0)
                 xx = self._ffn(p, xx)
                 return xx, kv if collect else None
-            x, ys = _scan(self._maybe_remat(body), x, params["layers"])
+            x, ys = jax.lax.scan(self._maybe_remat(body), x, params["layers"])
             if collect:
                 col["k"], col["v"] = ys
         out = self._head_in(params, x)
@@ -428,7 +419,7 @@ class Model:
             return xx, kv if collect else None
 
         def super_body(xx, p):
-            xx, kvl = _scan(self._maybe_remat(local_body), xx,
+            xx, kvl = jax.lax.scan(self._maybe_remat(local_body), xx,
                                    p["local"])
             xx, kvg = self._attn_train(p["global"], xx, sin, cos, window=0)
             xx = self._ffn(p["global"], xx)
@@ -436,12 +427,12 @@ class Model:
 
         col: dict[str, Any] = {}
         stacked = {"local": params["local"], "global": params["global"]}
-        x, ys = _scan(self._maybe_remat(super_body), x, stacked)
+        x, ys = jax.lax.scan(self._maybe_remat(super_body), x, stacked)
         if collect:
             (col["k_local"], col["v_local"]), (col["k_global"],
                                                col["v_global"]) = ys
         if self.n_tail:
-            x, ys = _scan(self._maybe_remat(local_body), x,
+            x, ys = jax.lax.scan(self._maybe_remat(local_body), x,
                                  params["tail"])
             if collect:
                 col["k_tail"], col["v_tail"] = ys
@@ -466,7 +457,7 @@ class Model:
         for k, (lo, hi) in enumerate(zip((-1,) + hy.attn_after, ends)):
             if hi > lo:
                 run = jax.tree.map(lambda a: a[lo + 1:hi + 1], params["mamba"])
-                x, st = _scan(self._maybe_remat(mamba_body), x, run)
+                x, st = jax.lax.scan(self._maybe_remat(mamba_body), x, run)
                 states.append(st)
             if k < len(hy.attn_after):
                 a = params["shared_attn"] if hy.shared else jax.tree.map(
@@ -493,7 +484,7 @@ class Model:
             xx = self._ffn(p, xx)
             return xx, None
 
-        h, _ = _scan(self._maybe_remat(enc_body), h, params["enc"])
+        h, _ = jax.lax.scan(self._maybe_remat(enc_body), h, params["enc"])
         h = rms_norm(h, params["ln_enc_final"], c.norm_eps)
 
         tokens = batch["tokens"]
@@ -507,7 +498,7 @@ class Model:
             xx = self._ffn(p, xx)
             return xx, (kvs, kvc) if collect else None
 
-        x, ys = _scan(self._maybe_remat(dec_body), x, params["dec"])
+        x, ys = jax.lax.scan(self._maybe_remat(dec_body), x, params["dec"])
         out = self._head_in(params, x)
         if collect:
             col = {}
@@ -535,7 +526,7 @@ class Model:
             xx = self._ffn(p, xx)
             return xx, None
 
-        h, _ = _scan(self._maybe_remat(enc_body), h, params["enc"])
+        h, _ = jax.lax.scan(self._maybe_remat(enc_body), h, params["enc"])
         return rms_norm(h, params["ln_enc_final"], c.norm_eps)
 
     def cross_kv(self, params: Params, enc_out: jax.Array):
@@ -755,7 +746,7 @@ class Model:
                     with jax.named_scope("ffn"):
                         xx = self._ffn(p, xx)
                 return (xx, kv), None
-            (x, (newc["k"], newc["v"])), _ = _scan(
+            (x, (newc["k"], newc["v"])), _ = jax.lax.scan(
                 body, (x, (cache["k"], cache["v"])),
                 (params["layers"], jnp.arange(c.n_layers)))
         elif c.window > 0:
@@ -767,7 +758,7 @@ class Model:
                 with jax.named_scope("layer"):
                     xx, cs = self._ssm_decode(p, xx, cs, i)
                 return (xx, cs), None
-            (x, (newc["conv"], newc["state"])), _ = _scan(
+            (x, (newc["conv"], newc["state"])), _ = jax.lax.scan(
                 body, (x, (cache["conv"], cache["state"])),
                 (params["layers"], jnp.arange(c.n_layers)))
         elif c.kind == "hybrid":
@@ -783,7 +774,7 @@ class Model:
                     with jax.named_scope("ffn"):
                         xx = self._ffn(p, xx)
                 return (xx, kv), None
-            (x, (newc["k_self"], newc["v_self"])), _ = _scan(
+            (x, (newc["k_self"], newc["v_self"])), _ = jax.lax.scan(
                 body, (x, (cache["k_self"], cache["v_self"])),
                 (params["dec"], cache["k_cross"], cache["v_cross"],
                  jnp.arange(c.n_layers)))
@@ -809,7 +800,7 @@ class Model:
         def super_body(carry, xs):
             xx, kvg = carry
             p, kl, vl, i = xs
-            xx, (kl, vl) = _scan(local_body, xx, (p["local"], kl, vl))
+            xx, (kl, vl) = jax.lax.scan(local_body, xx, (p["local"], kl, vl))
             with jax.named_scope("layer"):
                 xx, kvg = self._attn_decode(p["global"], xx, kvg, pos,
                                             layer=i)
@@ -819,12 +810,12 @@ class Model:
 
         stacked = {"local": params["local"], "global": params["global"]}
         (x, (newc["k_global"], newc["v_global"])), (
-            newc["k_local"], newc["v_local"]) = _scan(
+            newc["k_local"], newc["v_local"]) = jax.lax.scan(
             super_body, (x, (cache["k_global"], cache["v_global"])),
             (stacked, cache["k_local"], cache["v_local"],
              jnp.arange(self.n_super)))
         if self.n_tail:
-            x, (newc["k_tail"], newc["v_tail"]) = _scan(
+            x, (newc["k_tail"], newc["v_tail"]) = jax.lax.scan(
                 local_body, x, (params["tail"], cache["k_tail"],
                                 cache["v_tail"]))
         return x
@@ -860,9 +851,10 @@ class Model:
             return (xx, kv, cs), None
 
         init = (x, (cache["k"], cache["v"]), (cache["conv"], cache["state"]))
-        (x, (newc["k"], newc["v"]), (newc["conv"], newc["state"])), _ = _scan(
-            body, init, (params["mamba"], jnp.arange(hy.n_mamba),
-                         jnp.asarray(hy.attn_index())))
+        xs = (params["mamba"], jnp.arange(hy.n_mamba),
+              jnp.asarray(hy.attn_index()))
+        (x, (newc["k"], newc["v"]), (newc["conv"], newc["state"])), _ = \
+            jax.lax.scan(body, init, xs)
         return x
 
     # -------------------------------------------------------------- prefill

@@ -27,9 +27,9 @@ argument (duck-typed, optional, default off), so the layering in
     generators for CI, and trace/schedule alignment into
     :class:`CaptureWindow` calibration rows.
 
-See docs/observability.md for the event/metric catalog and overhead
-numbers (``benchmarks/sched_perf.py`` gates the tracer at <5% period
-inflation on the threaded runtime hot path).
+See docs/observability.md for the event/metric catalog and the overhead
+gate (``benchmarks/sched_perf.py --check`` holds the tracer under 5%
+period inflation on the threaded runtime hot path, within one run).
 """
 from .export import load_trace, to_chrome_events, write_perfetto  # noqa: F401
 from .metrics import MetricsRegistry  # noqa: F401

@@ -22,7 +22,6 @@ The planner also powers elastic scaling: when the device pool changes
 from __future__ import annotations
 
 import dataclasses
-import math
 
 from repro.core import (
     BIG,
@@ -240,7 +239,7 @@ def plan_pipeline(cfg: ModelConfig, *, system: HeterogeneousSystem,
     ``ParetoPoint`` from a previous cap query, sorted by period as the
     builders return it) to re-plan under a sequence of caps without
     re-sweeping — frontier construction, not the query, is the
-    expensive part (see BENCH_sched.json).
+    expensive part (``benchmarks/sched_perf.py`` times both).
     """
     chain, _ = model_chain(cfg, tokens_per_step=tokens_per_step, mode=mode,
                            system=system)

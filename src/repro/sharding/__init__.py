@@ -5,7 +5,6 @@ from .rules import (  # noqa: F401
     current_ctx,
     current_mesh,
     logical_sharding,
-    scan_unroll,
     set_ctx,
     shard,
     shard_map,

@@ -6,8 +6,7 @@ Model code names tensor dimensions with *logical* axes ('batch', 'ff',
 divisible by the product of its mapped mesh axes, the mapping silently falls
 back to replication for that dimension — this is what makes every assigned
 architecture (e.g. arctic's 56 q-heads or phi3's 10 kv-heads on a 16-way
-model axis) lower cleanly on the same rule set; the roofline report calls out
-where fallbacks cost parallelism.
+model axis) lower cleanly on the same rule set.
 """
 from __future__ import annotations
 
@@ -47,9 +46,6 @@ class ShardingCtx:
     rules: dict[str, tuple[str, ...]] = dataclasses.field(
         default_factory=lambda: dict(DEFAULT_RULES)
     )
-    # Analysis mode: unroll every lax.scan so XLA's cost_analysis counts each
-    # iteration (while-bodies are otherwise counted once) — see dryrun.py.
-    unroll: bool = False
 
     def mesh_axes(self, logical: str) -> tuple[str, ...]:
         if self.mesh is None:
@@ -116,10 +112,10 @@ def current_mesh() -> Mesh | None:
 
 
 @contextlib.contextmanager
-def use_ctx(mesh: Mesh | None, rules: dict[str, tuple[str, ...]] | None = None,
-            unroll: bool = False):
+def use_ctx(mesh: Mesh | None,
+            rules: dict[str, tuple[str, ...]] | None = None):
     prev = getattr(_tls, "ctx", None)
-    ctx = ShardingCtx(mesh=mesh, unroll=unroll)
+    ctx = ShardingCtx(mesh=mesh)
     if rules:
         ctx.rules.update(rules)
     set_ctx(ctx)
@@ -127,11 +123,6 @@ def use_ctx(mesh: Mesh | None, rules: dict[str, tuple[str, ...]] | None = None,
         yield ctx
     finally:
         set_ctx(prev)
-
-
-def scan_unroll() -> bool:
-    """Whether model-code scans should unroll (analysis mode)."""
-    return current_ctx().unroll
 
 
 def axis_size(logical: str) -> int:

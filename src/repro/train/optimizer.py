@@ -2,18 +2,16 @@
 
 The int8 variant ("adamw8") stores both Adam moments as int8 with per-block
 fp32 scales (block = 128 along the last axis). This is the optimizer-state
-compression that makes the 1T-param `kimi-k2` cell fit v5e HBM (see
-DESIGN.md §4) and doubles as the framework's state-compression feature:
-moments shrink 4x, and with ZeRO-1 sharding over the data axis the per-chip
-optimizer footprint for kimi-k2 drops from 16 GB (fp32) to ~0.25 GB.
+compression that makes the 1T-param `kimi-k2` cell fit v5e HBM and doubles
+as the framework's state-compression feature: moments shrink 4x, and with
+ZeRO-1 sharding over the data axis the per-chip optimizer footprint for
+kimi-k2 drops from 16 GB (fp32) to ~0.25 GB.
 
 Both variants are pure pytree transforms — no optax dependency.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -135,10 +133,8 @@ def apply_updates(params, grads, opt_state, cfg: OptConfig):
         # Chunk giant leaves (MoE expert stacks, embedding tables) so the
         # dequantized fp32 moment transients stay bounded — otherwise buffer
         # assignment wants tens of GB/device at the 1T scale. The chunk
-        # count is capped at 64 (bounded dispatch overhead); analysis mode
-        # (scan_unroll) skips chunking so per-op accounting stays exact.
-        from repro.sharding import scan_unroll
-        if scan_unroll() or p.size <= (1 << 28):
+        # count is capped at 64 (bounded dispatch overhead).
+        if p.size <= (1 << 28):
             return upd_flat(p, g, m, v)
         n = p.shape[0]
         chunks = next((c for c in range(min(n, 64), 1, -1) if n % c == 0), 1)

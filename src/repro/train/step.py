@@ -14,7 +14,7 @@ The step function is a single jit-compiled program:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +29,7 @@ class TrainConfig:
     n_microbatches: int = 1
     opt: OptConfig = dataclasses.field(default_factory=OptConfig)
     # bf16 accumulation halves the persistent grad buffer — required to fit
-    # the ~1T-param cells in 16 GB/chip HBM (see DESIGN.md §4).
+    # the ~1T-param cells in 16 GB/chip HBM.
     grad_accum_dtype: str = "float32"
     # FSDP: additionally shard the parameters over the ('pod', 'data') axes
     # (gathered per layer by GSPMD). Enabled for the >100B archs.
@@ -155,13 +155,11 @@ def make_train_step(model: Model, tcfg: TrainConfig,
                     lambda a, g: a + g.astype(a.dtype), gacc, grads)
                 return (loss_sum + loss, gacc), None
 
-            from repro.sharding import scan_unroll
             acc_dt = jnp.dtype(tcfg.grad_accum_dtype)
             g0 = constrain(jax.tree.map(
                 lambda p: jnp.zeros(p.shape, acc_dt), params))
             (loss_sum, grads), _ = jax.lax.scan(
-                acc_body, (jnp.zeros(()), g0), mbs,
-                unroll=scan_unroll())
+                acc_body, (jnp.zeros(()), g0), mbs)
             loss = loss_sum / n_mb
             grads = jax.tree.map(lambda g: g / n_mb, grads)
 

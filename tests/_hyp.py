@@ -4,13 +4,14 @@ A module-level ``pytest.importorskip("hypothesis")`` would hide every
 test in the file when hypothesis is absent — including plain tests that
 never touch it. Importing ``given``/``settings``/``st`` from here
 instead keeps those running: without hypothesis, ``@given`` becomes a
-skip marker and ``st`` a chainable dummy whose strategies are never
-executed.
+skip marker, ``@example`` a no-op and ``st`` a chainable dummy whose
+strategies are never executed.
 """
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import (  # noqa: F401
+        example, given, settings, strategies as st)
 
     HAS_HYPOTHESIS = True
 except ModuleNotFoundError:  # pragma: no cover - depends on environment
@@ -35,3 +36,5 @@ except ModuleNotFoundError:  # pragma: no cover - depends on environment
             return fn
 
         return deco
+
+    example = settings

@@ -1,8 +1,7 @@
 import os
 import sys
 
-# Tests see the real (single) CPU device — the 512-device override belongs
-# ONLY to the dry-run (repro.launch.dryrun). Distributed-parity tests spawn
+# Tests see the real (single) CPU device. Distributed-parity tests spawn
 # subprocesses with their own XLA_FLAGS instead.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 

@@ -3,7 +3,7 @@ attention, flash-decoding, expert-parallel MoE, vocab-parallel embed/loss,
 sharded train step) must equal their single-device references.
 
 These run in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8
-so the main test process keeps seeing 1 device (per the dry-run contract).
+so the main test process keeps seeing 1 device (see tests/conftest.py).
 """
 import os
 import subprocess

@@ -17,7 +17,7 @@ import math
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from _hyp import example, given, settings, st
 from repro.core import BIG, LITTLE, make_chain
 from repro.core.chain import TaskChain
 from repro.core.dvfs import dvfs_tables, scale_chain
@@ -197,6 +197,7 @@ def test_sweep_budgets_matches_reference(seed, n, sr, b, l):
     l=st.integers(0, 4),
     ladder=st.sampled_from(LADDERS),
 )
+@example(seed=0, n=3, sr=0.0, b=2, l=1, ladder=(0.6, 1.0))
 def test_sweep_budgets_freq_matches_reference(seed, n, sr, b, l, ladder):
     chain = _chain(seed, n, sr)
     power = _model(ladder)
@@ -252,6 +253,7 @@ def test_min_energy_dp_variant_matches_reference(seed, n, sr, b, l,
     ladder=st.sampled_from(LADDERS),
     k=st.integers(1, 2),
 )
+@example(seed=0, n=3, sr=0.0, b=2, l=1, ladder=(1.0,), k=2)
 def test_sweep_budgets_variant_matches_reference(seed, n, sr, b, l,
                                                  ladder, k):
     chain = _chain(seed, n, sr)
@@ -272,6 +274,7 @@ def test_sweep_budgets_variant_matches_reference(seed, n, sr, b, l,
     l=st.integers(0, 4),
     ladder=st.sampled_from(LADDERS),
 )
+@example(seed=3921, n=5, sr=0.0, b=1, l=2, ladder=(1.0,))
 def test_frontiers_match_reference_composition(seed, n, sr, b, l, ladder):
     """pareto_frontier / dvfs_frontier == non-dominated reference sweep
     refined by the reference DP (the pre-PR composition)."""
